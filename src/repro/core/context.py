@@ -12,17 +12,19 @@ from typing import Dict, Tuple
 from jax._src import source_info_util
 
 
+def user_frames(eqn):
+    """User-code frames of a jaxpr eqn's traceback, innermost first
+    (empty when the eqn was traced without one)."""
+    tb = eqn.source_info.traceback
+    if tb is None:
+        return []
+    return list(source_info_util.user_frames(tb))
+
+
 def context_of_eqn(eqn, max_frames: int = 12) -> Tuple[str, ...]:
     """Full calling context for a jaxpr eqn from its source_info."""
-    frames = []
-    try:
-        tb = eqn.source_info.traceback
-        for f in source_info_util.user_frames(eqn.source_info):
-            frames.append(f"{f.file_name.split('/')[-1]}:{f.start_line}:{f.function_name}")
-            if len(frames) >= max_frames:
-                break
-    except Exception:
-        pass
+    frames = [f"{f.file_name.split('/')[-1]}:{f.start_line}:{f.function_name}"
+              for f in user_frames(eqn)[:max_frames]]
     frames.reverse()                      # outermost -> innermost
     frames.append(str(eqn.primitive.name))
     return tuple(frames)

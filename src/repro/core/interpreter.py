@@ -29,10 +29,7 @@ from typing import Any, Dict, List, Optional
 
 import jax
 import numpy as np
-try:
-    from jax.extend.core import Literal
-except ImportError:  # pragma: no cover
-    from jax.core import Literal
+from jax.extend.core import Literal
 
 from repro.configs.base import ProfilerConfig
 from repro.core.context import context_of_eqn

@@ -46,12 +46,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 
-try:
-    from jax.extend.core import Literal
-except ImportError:  # pragma: no cover
-    from jax.core import Literal
+from jax.extend.core import Literal
 
-from repro.core.context import context_of_eqn
+from repro.core.context import context_of_eqn, user_frames
 from repro.core.findings import TIER_STATIC, Finding, WasteProfile
 
 # primitives that *store into* a region of an existing buffer
@@ -64,21 +61,16 @@ _IDENTITY_PRIMS = {"add": 0.0, "sub": 0.0, "mul": 1.0, "div": 1.0}
 
 
 def _nbytes(aval) -> float:
-    try:
-        return float(np.prod(aval.shape, dtype=np.float64)
-                     * np.dtype(aval.dtype).itemsize)
-    except Exception:
+    """Bytes of an array aval; 0 for tokens and extended (key) dtypes."""
+    if not isinstance(getattr(aval, "dtype", None), np.dtype):
         return 0.0
+    return float(np.prod(aval.shape, dtype=np.float64) * aval.dtype.itemsize)
 
 
 def _src_of(eqn) -> Tuple[Optional[str], int]:
     """Innermost user frame of an eqn: (absolute file path, line)."""
-    try:
-        from jax._src import source_info_util
-        for f in source_info_util.user_frames(eqn.source_info):
-            return f.file_name, int(f.start_line)
-    except Exception:
-        pass
+    for f in user_frames(eqn):
+        return f.file_name, int(f.start_line)
     return None, 0
 
 
@@ -146,10 +138,7 @@ class JaxprLinter:
 
     @staticmethod
     def _params_key(params: Dict[str, Any]) -> str:
-        try:
-            return repr(sorted(params.items(), key=lambda kv: kv[0]))
-        except Exception:
-            return repr(sorted(params.keys()))
+        return repr(sorted(params.items(), key=lambda kv: kv[0]))
 
     # -- findings -------------------------------------------------------
     def _flag(self, kind: str, eqn, *, bytes=0.0, count=1, c2_eqn=None,

@@ -12,7 +12,7 @@ speculative draft window registers an :class:`ObjectRecord` carrying
 - its kind (``kv_page`` / ``param`` / ``opt_state`` / ``draft_window``),
 - byte size and the **allocation site** (file:line:function of the
   registering caller — ``PageAllocator.alloc``, ``params.init_tree``,
-  ``adamw.init``), and
+  ``train/state.py create``), and
 - an optional zero-argument ``reader`` returning the current contents
   as a numpy array, which is what lets `core/replicas.py` content-hash
   live objects without the registry ever holding device buffers.

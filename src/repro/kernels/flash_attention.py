@@ -11,6 +11,9 @@ TPU adaptation notes (vs. the canonical CUDA flash-attention):
   * GQA is handled in the index_map (q head h reads kv head h // G), so
     no KV replication is materialized in HBM.
 
+Differentiable through a ``custom_vjp`` whose backward is the pure-JAX
+recompute rule of ``flash_xla`` (no Pallas backward kernel).
+
 Validated in interpret mode on CPU against ``ref.attention_ref``.
 """
 from __future__ import annotations
@@ -24,28 +27,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import flash_xla
+
 NEG_INF = -1e30
 
 
-def online_softmax_step(s, v, m_scr, l_scr, acc_scr):
-    """One flash accumulation step, shared by every attention kernel in
-    this package (causal flash, paged decode, paged window).
+def online_softmax_update(s, v, m_prev, l_prev, acc_prev):
+    """One flash accumulation step on values, shared by every attention
+    kernel in this package (causal flash, paged decode, paged window).
 
     ``s``: (rows, cols) masked f32 scores; ``v``: (cols, D) f32 values;
-    the three scratch refs are the (rows, 1) running max / denominator
-    and the (rows, D) output accumulator, persisted across the innermost
-    grid sweep."""
-    m_prev = m_scr[...]
+    ``m_prev``/``l_prev``: (rows, 1) running max / denominator;
+    ``acc_prev``: (rows, D) output accumulator. Returns the updated
+    ``(m, l, acc)``."""
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+    l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_new = acc_prev * corr + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+    return m_new, l_new, acc_new
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
+                  acc_scr, *,
                   scale: float, causal: bool, block_q: int, block_k: int,
                   seq_len: int):
     qi = pl.program_id(1)
@@ -81,12 +86,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         if causal:
             mask = mask & (qpos >= kpos)
         s = jnp.where(mask, s, NEG_INF)
-        online_softmax_step(s, v, m_scr, l_scr, acc_scr)
+        m_scr[...], l_scr[...], acc_scr[...] = online_softmax_update(
+            s, v, m_scr[...], l_scr[...], acc_scr[...])
 
     @pl.when(ki == nk - 1)
     def _fin():
-        l = l_scr[...]
-        l = jnp.where(l == 0.0, 1.0, l)
+        l = jnp.where(l_scr[...] == 0.0, 1.0, l_scr[...])
+        lse_ref[0] = m_scr[...] + jnp.log(l)
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
@@ -94,7 +100,33 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool = False) -> jax.Array:
-    """q: (B, Sq, Hq, D), k/v: (B, Skv, Hkv, D) -> (B, Sq, Hq, D)."""
+    """q: (B, Sq, Hq, D), k/v: (B, Skv, Hkv, D) -> (B, Sq, Hq, D).
+
+    Differentiable: the backward is ``flash_xla``'s recompute rule over
+    the (q, k, v, out, lse) residuals this forward saves."""
+    return _flash(q, k, v, causal, block_q, block_k, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, block_q, block_k, interpret):
+    return _flash_forward(q, k, v, causal, block_q, block_k, interpret)[0]
+
+
+def _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret):
+    out, lse = _flash_forward(q, k, v, causal, block_q, block_k, interpret)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_bwd_rule(causal, block_q, block_k, interpret, res, dout):
+    return flash_xla._bwd_rule(causal, 0, flash_xla.DEFAULT_CHUNK, res, dout)
+
+
+_flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
+    """Pallas forward: (out (B, Sq, Hq, D), lse (B, Sq, Hkv, G)) — lse in
+    ``flash_xla``'s residual layout."""
     B, Sq, Hq, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
@@ -126,7 +158,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         b = bh // Hq
         return (b * Hkv + h // G, ki, 0)
 
-    out = pl.pallas_call(
+    out, lse = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_len=Skv),
         grid=grid,
@@ -135,16 +167,20 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pl.BlockSpec((1, block_k, D), kv_index),
             pl.BlockSpec((1, block_k, D), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, block_q, D), q_index),
-        out_shape=jax.ShapeDtypeStruct((B * Hq, Sq_p, D), q.dtype),
+        out_specs=[pl.BlockSpec((1, block_q, D), q_index),
+                   pl.BlockSpec((1, block_q, 1), q_index)],
+        out_shape=[jax.ShapeDtypeStruct((B * Hq, Sq_p, D), q.dtype),
+                   jax.ShapeDtypeStruct((B * Hq, Sq_p, 1), jnp.float32)],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),   # running max m
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denom l
             pltpu.VMEM((block_q, D), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qt.reshape(B * Hq, Sq_p, D), kt.reshape(B * Hkv, Skv_p, D),
       vt.reshape(B * Hkv, Skv_p, D))
 
     out = out.reshape(B, Hq, Sq_p, D)[:, :, :Sq].transpose(0, 2, 1, 3)
-    return out
+    lse = lse.reshape(B, Hq, Sq_p)[:, :, :Sq].transpose(0, 2, 1)
+    return out, lse.reshape(B, Sq, Hkv, G)

@@ -1,12 +1,11 @@
 """Fused paged window-attention kernel (Pallas TPU): causal flash
-prefill with a paged-write epilogue, and width-(k+1) speculative verify.
+prefill over a paged pool, and width-(k+1) speculative verify.
 
 One kernel serves both serving forwards that push an S-token *window*
 at per-slot offsets ``idx`` against a paged KV pool:
 
-  * prefill (``LM.prefill``) — S prompt tokens; the window's K/V rows
-    are written straight into the page pool from inside the kernel
-    (aliased pool outputs, no host-side scatter);
+  * prefill (``LM.prefill``) — S prompt tokens whose K/V rows are stored
+    into the page pool;
   * verify (``LM.verify``) — S = k+1 draft tokens; ``store=True`` is
     spec="overwrite" (all rows stored, rejected rows become dead
     stores), ``store=False`` is spec="defer" (rollback: pool untouched,
@@ -14,24 +13,25 @@ at per-slot offsets ``idx`` against a paged KV pool:
 
 The committed history is gathered from the pool *inside* the kernel via
 the scalar-prefetched page table (no ``paged_gather`` materialization);
-the window K/V ride in a separate operand. The innermost grid dim runs
-``M`` committed-page steps, one window step, then (store mode)
-``Wp`` store-epilogue steps that write the window rows into their pages.
+the window K/V ride in a separate operand. One grid step covers every
+head of a slot, so each block's last two dims are the full (Hkv, D)
+head extents the TPU tiling accepts. The innermost grid dim runs ``M``
+committed-page steps, one window step, then (store mode) ``Wc``
+store-site steps over the pages the window lands in.
 
 Waste counters ([stored, silent, dropped] per slot, see
 ``kernels/paged_attention.py`` and DESIGN.md § Kernel tier) are
-measured at the store epilogue — the store site — by comparing each
-page tile against the rows about to overwrite it with
-``core.events.silent_mask`` semantics, *before* the tile is rewritten.
-
-Store semantics: the aliased pool outputs are read-modify-written (see
-the in-kernel comment) — input refs of aliased operands are snapshots,
-so all epilogue reads go through the output refs, and visits that store
-nothing leave their block untouched. Grid dims are declared "arbitrary"
-so the sequential-revisit semantics interpret mode tests are the
-semantics the TPU pipeline must honor; the COW invariant of
-`serve/kv_cache.py` (a page being extended is exclusively mapped; shared
-pages are read-only) is what makes the per-slot writes race-free.
+measured at those store-site steps, by comparing each page tile against
+the window rows about to overwrite it with ``core.events.silent_mask``
+semantics. The kernel itself never writes the pool: a Pallas TPU output
+block is not loaded from HBM, so a read-modify-write epilogue through
+aliased outputs would store stale VMEM over the pages it did not mean
+to touch. The rows land instead through one ``ref.paged_update``
+scatter after the kernel (O(B*S*Hkv*D), the rows the counters
+describe), which is the same page-table routing the decode kernel uses.
+The COW invariant of `serve/kv_cache.py` (a page being extended is
+exclusively mapped; shared pages are read-only) keeps the per-slot
+stores race-free.
 """
 from __future__ import annotations
 
@@ -44,25 +44,26 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.events import silent_mask
-from repro.kernels.flash_attention import NEG_INF, online_softmax_step
+from repro.kernels import ref as _ref
+from repro.kernels.flash_attention import NEG_INF, online_softmax_update
+from repro.kernels.paged_attention import counter_row
 
 
 def _window_kernel(pt_ref, idx_ref, q_ref, kw_ref, vw_ref, wv_ref,
                    k_ref, v_ref,
-                   o_ref, lse_ref, cnt_ref, ok_ref, ov_ref,
-                   m_scr, l_scr, acc_scr, cnt_scr, *,
-                   scale: float, ps: int, G: int, S: int, M: int,
-                   block_q: int, store: bool, tol: float):
+                   o_ref, lse_ref, cnt_ref,
+                   m_scr, l_scr, acc_scr, *,
+                   scale: float, ps: int, Hkv: int, G: int, S: int, M: int,
+                   block_q: int, tol: float):
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    qi = pl.program_id(2)
-    mi = pl.program_id(3)
+    qi = pl.program_id(1)
+    mi = pl.program_id(2)
     idx = idx_ref[b]
-    w0 = jnp.maximum(idx, 0) // ps
+    rows = G * block_q                       # query rows of one kv head
 
-    @pl.when((h == 0) & (qi == 0) & (mi == 0))
+    @pl.when((qi == 0) & (mi == 0))
     def _zero_cnt():
-        cnt_scr[...] = jnp.zeros_like(cnt_scr)
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
     @pl.when(mi == 0)
     def _init():
@@ -70,102 +71,76 @@ def _window_kernel(pt_ref, idx_ref, q_ref, kw_ref, vw_ref, wv_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    def attend(k_of, v_of, mask):
+        """Flash step of every kv head's G query heads against (cols, D)
+        keys/values; ``mask``: (rows, cols) attendable positions."""
+        for g in range(Hkv):
+            q = q_ref[0, g * G:(g + 1) * G].astype(jnp.float32)
+            q = q.reshape(rows, q.shape[-1])              # (G*bq, D)
+            k = k_of(g).astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(mask, s * scale, NEG_INF)
+            r = pl.ds(g * rows, rows)
+            m_scr[r], l_scr[r], acc_scr[r] = online_softmax_update(
+                s, v_of(g).astype(jnp.float32), m_scr[r], l_scr[r],
+                acc_scr[r])
+
     # ---- committed-history page steps -------------------------------
     page = pt_ref[b, jnp.clip(mi, 0, M - 1)]
 
     @pl.when((mi < M) & (idx >= 1) & (page >= 0) & (mi * ps < idx))
     def _attend_page():
-        q = q_ref[0, 0].astype(jnp.float32)               # (bq, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)            # (ps, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        kpos = mi * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < idx, s, NEG_INF)
-        online_softmax_step(s, v, m_scr, l_scr, acc_scr)
+        kpos = mi * ps + jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1)
+        attend(lambda g: k_ref[0, :, g], lambda g: v_ref[0, :, g],
+               kpos < idx)
 
     # ---- window step: in-window causal attention --------------------
     @pl.when((mi == M) & (idx >= 0))
     def _attend_window():
-        q = q_ref[0, 0].astype(jnp.float32)               # (bq, D)
-        k = kw_ref[0, :, 0].astype(jnp.float32)           # (S, D)
-        v = vw_ref[0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        r = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = (c <= r) & (wv_ref[0][None, :] > 0)
-        s = jnp.where(mask, s, NEG_INF)
-        online_softmax_step(s, v, m_scr, l_scr, acc_scr)
+        shape = (G, block_q, S)
+        r = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        c = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+        mask = ((c <= r) & (wv_ref[0] > 0)).reshape(rows, S)
+        attend(lambda g: kw_ref[0, pl.ds(ps, S), g],
+               lambda g: vw_ref[0, pl.ds(ps, S), g], mask)
 
     @pl.when(mi == M)
     def _fin():
         l = l_scr[...]
-        lse_ref[0, 0] = jnp.where(
-            l > 0.0, m_scr[...] + jnp.log(jnp.where(l > 0.0, l, 1.0)),
-            NEG_INF)[:, 0]
+        lse = jnp.where(l > 0.0, m_scr[...] + jnp.log(
+            jnp.where(l > 0.0, l, 1.0)), NEG_INF)
+        lse_ref[0] = lse.reshape(lse_ref.shape[1:])
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l).reshape(o_ref.shape[1:]).astype(
+            o_ref.dtype)
 
-    # ---- store epilogue: write window rows into their pages ---------
-    #
-    # The aliased pool *outputs* are read-modify-written: o-ref reads see
-    # the live buffer (the aliased input's value until the page is first
-    # written), and visits that store nothing leave the block untouched,
-    # so pages shared across slots / revisited across (h, qi) sweeps are
-    # never clobbered with stale content. (Input refs of aliased
-    # operands are snapshots — they serve only the committed-history
-    # attention reads, which never overlap this kernel's stores.)
-    if store:
-        pdt = ok_ref.dtype
-
-        @pl.when((mi > M) & (idx >= 0))
-        def _store():
-            j = mi - (M + 1)
-            page_i = w0 + j
-            entry = pt_ref[b, jnp.clip(page_i, 0, M - 1)]
-            page_ok = (page_i < M) & (entry >= 0)
-
-            offs = jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
-            sw = page_i * ps + offs - idx                 # window row per off
-            sel = (sw >= 0) & (sw < S)
-            oh = ((sw == jax.lax.broadcasted_iota(jnp.int32, (ps, S), 1))
-                  & sel).astype(jnp.float32)              # one-hot (ps, S)
-
-            def rows(w_ref):
-                w = w_ref[0, :, 0].astype(jnp.float32)    # (S, D)
-                r = jax.lax.dot_general(
-                    oh, w, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                return r.astype(pdt)                      # exact row copies
-
-            old_k = ok_ref[0, :, 0]
-            old_v = ov_ref[0, :, 0]
-            write = sel & page_ok
-            new_k = jnp.where(write, rows(kw_ref), old_k)
-            new_v = jnp.where(write, rows(vw_ref), old_v)
-            ok_ref[0, :, 0] = new_k
-            ov_ref[0, :, 0] = new_v
-
-            # store-site counters, measured against pre-store content at
-            # the first visit of each (kv head, page)
-            @pl.when((h % G == 0) & (qi == 0))
-            def _count():
-                D = old_k.shape[-1]
-                n_sel = jnp.sum(sel.astype(jnp.int32))
-                sil = (jnp.sum(jnp.where(sel, silent_mask(
-                            old_k.astype(jnp.float32),
-                            new_k.astype(jnp.float32), tol), False),
-                            dtype=jnp.int32)
-                       + jnp.sum(jnp.where(sel, silent_mask(
-                            old_v.astype(jnp.float32),
-                            new_v.astype(jnp.float32), tol), False),
-                            dtype=jnp.int32))
-                cnt_scr[0, 0] += jnp.where(page_ok, 2 * D * n_sel, 0)
-                cnt_scr[0, 1] += jnp.where(page_ok, sil, 0)
-                cnt_scr[0, 2] += jnp.where(page_ok, 0, 2 * D * n_sel)
-
-    cnt_ref[...] = cnt_scr[...]
+    # ---- store-site steps: count the window rows landing in each page --
+    # (store mode only; the grid has no such steps otherwise). Page j of
+    # the window holds window rows [page_i*ps - idx, +ps), a contiguous
+    # range of the ps-row-padded window operand.
+    @pl.when((mi > M) & (qi == 0) & (idx >= 0))
+    def _count():
+        page_i = jnp.maximum(idx, 0) // ps + (mi - (M + 1))
+        entry = pt_ref[b, jnp.clip(page_i, 0, M - 1)]
+        page_ok = (page_i < M) & (entry >= 0)
+        start = page_i * ps - idx                 # window row at offset 0
+        sw = start + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
+        sel = (sw >= 0) & (sw < S)                # (ps, 1)
+        lo = jnp.clip(start, -ps, S) + ps
+        D = k_ref.shape[-1]
+        n_sel = jnp.sum(jnp.where(sel, 1, 0), dtype=jnp.int32)
+        sil = jnp.zeros((), jnp.int32)
+        for g in range(Hkv):
+            for w_ref, p_ref in ((kw_ref, k_ref), (vw_ref, v_ref)):
+                new = w_ref[0, pl.ds(lo, ps), g].astype(jnp.float32)
+                old = p_ref[0, :, g].astype(jnp.float32)   # pre-store
+                hit = silent_mask(old, new, tol) & sel
+                sil += jnp.sum(jnp.where(hit, 1, 0), dtype=jnp.int32)
+        n = 2 * Hkv * D * n_sel
+        cnt_ref[...] += counter_row(jnp.where(page_ok, n, 0),
+                                    jnp.where(page_ok, sil, 0),
+                                    jnp.where(page_ok, 0, n))
 
 
 def paged_window_attention(q: jax.Array, k_win: jax.Array, v_win: jax.Array,
@@ -178,13 +153,14 @@ def paged_window_attention(q: jax.Array, k_win: jax.Array, v_win: jax.Array,
     """q: (B, S, Hq, D) at per-slot offsets idx (B,); k_win/v_win:
     (B, S, Hkv, D); pool: (P, page, Hkv, D); pt: (B, M).
 
-    Returns ``(out, lse, counters, new_pool_k, new_pool_v)``; with
-    ``store=False`` the pools come back unchanged (and are not donated).
-    Matches the ref compositions used by ``models.layers.apply_attention``:
-    ``paged_update -> paged_gather -> attention_ref`` for store mode, the
-    spliced-gather "defer" path otherwise. Idle slots (idx < 0) attend
-    nothing and come back zero (the ref path yields NaN there; the
-    engine discards both).
+    Returns ``(out, lse, counters, new_pool_k, new_pool_v)``: out
+    (B, S, Hq, D); lse (B, Hq, S); counters (B, 3) int32. With
+    ``store=False`` the pools come back unchanged and the counters are
+    zero. Matches the ref compositions used by
+    ``models.layers.apply_attention``: ``paged_update -> paged_gather ->
+    attention_ref`` for store mode, the spliced-gather "defer" path
+    otherwise. Idle slots (idx < 0) attend nothing and come back zero
+    (the ref path yields NaN there; the engine discards both).
     """
     B, S, Hq, D = q.shape
     P, ps, Hkv, _ = pool_k.shape
@@ -206,6 +182,7 @@ def paged_window_attention(q: jax.Array, k_win: jax.Array, v_win: jax.Array,
         wv = (entry >= 0).astype(jnp.int32)
     else:
         wv = (gpos < M * ps).astype(jnp.int32)
+    wv = wv[:, None, :]                                      # (B, 1, S)
 
     block_q = min(block_q, max(S, 8))
     Sq_p = pl.cdiv(S, block_q) * block_q
@@ -214,96 +191,70 @@ def paged_window_attention(q: jax.Array, k_win: jax.Array, v_win: jax.Array,
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, Sq_p - S), (0, 0)))
     nq = Sq_p // block_q
 
-    kw = k_win.astype(pdt)
-    vw = v_win.astype(pdt)
+    # window rows in the pool dtype (the values a store lands), padded by
+    # one page on both sides for the store-site steps' page-aligned reads
+    pad = ((0, 0), (ps, ps), (0, 0), (0, 0))
+    kw = jnp.pad(k_win.astype(pdt), pad)
+    vw = jnp.pad(v_win.astype(pdt), pad)
 
-    Wp = pl.cdiv(S, ps) + 1 if store else 0
-    grid = (B, Hq, nq, M + 1 + Wp)
+    Wc = pl.cdiv(S, ps) + 1 if store else 0
+    grid = (B, nq, M + 1 + Wc)
 
-    def q_index(b, h, qi, mi, *_):
-        return (b, h, qi, 0)
+    def q_index(b, qi, mi, *_):
+        return (b, 0, qi, 0)
 
-    def win_index(b, h, qi, mi, *_):
-        return (b, 0, h // G, 0)
+    def slot_index(b, qi, mi, *_):
+        return (b, 0, 0, 0)
 
-    def wv_index(b, h, qi, mi, *_):
-        return (b, 0)
+    def wv_index(b, qi, mi, *_):
+        return (b, 0, 0)
 
-    def pool_index(b, h, qi, mi, pt_ref, idx_ref):
+    def pool_index(b, qi, mi, pt_ref, idx_ref):
         w0 = jnp.maximum(idx_ref[b], 0) // ps
         page_i = jnp.where(mi < M, mi, jnp.clip(w0 + mi - M - 1, 0, M - 1))
-        return (jnp.clip(pt_ref[b, page_i], 0, P - 1), 0, h // G, 0)
-
-    out_specs = [
-        pl.BlockSpec((1, 1, block_q, D), q_index),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, qi, mi, *_: (b, h, qi)),
-        pl.BlockSpec((1, 3), lambda b, h, qi, mi, *_: (b, 0)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((B, Hq, Sq_p, D), q.dtype),
-        jax.ShapeDtypeStruct((B, Hq, Sq_p), jnp.float32),
-        jax.ShapeDtypeStruct((B, 3), jnp.int32),
-    ]
-    kwargs = {}
-    if store:
-        out_specs += [pl.BlockSpec((1, ps, 1, D), pool_index),
-                      pl.BlockSpec((1, ps, 1, D), pool_index)]
-        out_shape += [jax.ShapeDtypeStruct(pool_k.shape, pdt),
-                      jax.ShapeDtypeStruct(pool_v.shape, pdt)]
-        # operand numbering includes the scalar-prefetch args: the pools
-        # are inputs 6, 7 of (pt, idx, q, kw, vw, wv, pool_k, pool_v)
-        kwargs["input_output_aliases"] = {6: 3, 7: 4}
+        return (jnp.clip(pt_ref[b, page_i], 0, P - 1), 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), q_index),
-            pl.BlockSpec((1, S, 1, D), win_index),
-            pl.BlockSpec((1, S, 1, D), win_index),
-            pl.BlockSpec((1, S), wv_index),
-            pl.BlockSpec((1, ps, 1, D), pool_index),
-            pl.BlockSpec((1, ps, 1, D), pool_index),
+            pl.BlockSpec((1, Hq, block_q, D), q_index),
+            pl.BlockSpec((1, S + 2 * ps, Hkv, D), slot_index),
+            pl.BlockSpec((1, S + 2 * ps, Hkv, D), slot_index),
+            pl.BlockSpec((1, 1, S), wv_index),
+            pl.BlockSpec((1, ps, Hkv, D), pool_index),
+            pl.BlockSpec((1, ps, Hkv, D), pool_index),
         ],
-        out_specs=out_specs,
+        out_specs=[
+            pl.BlockSpec((1, Hq, block_q, D), q_index),
+            pl.BlockSpec((1, Hq, block_q, 1), q_index),
+            pl.BlockSpec((1, 1, 3), wv_index),
+        ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((1, 3), jnp.int32),
+            pltpu.VMEM((Hq * block_q, 1), jnp.float32),   # running max
+            pltpu.VMEM((Hq * block_q, 1), jnp.float32),   # running denom
+            pltpu.VMEM((Hq * block_q, D), jnp.float32),   # accumulator
         ],
     )
-
-    def dummy_store_refs(fn):
-        if store:
-            return fn
-        # store=False has no pool outputs; pad the kernel signature
-        def wrapped(pt_ref, idx_ref, q_ref, kw_ref, vw_ref, wv_ref,
-                    k_ref, v_ref, o_ref, lse_ref, cnt_ref, *scr):
-            return fn(pt_ref, idx_ref, q_ref, kw_ref, vw_ref, wv_ref,
-                      k_ref, v_ref, o_ref, lse_ref, cnt_ref, None, None,
-                      *scr)
-        return wrapped
-
-    kernel = dummy_store_refs(functools.partial(
-        _window_kernel, scale=scale, ps=ps, G=G, S=S, M=M,
-        block_q=block_q, store=store, tol=tol))
-
-    res = pl.pallas_call(
-        kernel,
+    out, lse, cnt = pl.pallas_call(
+        functools.partial(_window_kernel, scale=scale, ps=ps, Hkv=Hkv, G=G,
+                          S=S, M=M, block_q=block_q, tol=tol),
         grid_spec=grid_spec,
-        out_shape=out_shape,
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary",) * 4),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, Hq, Sq_p, D), q.dtype),
+            jax.ShapeDtypeStruct((B, Hq, Sq_p, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, 3), jnp.int32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3),
         interpret=interpret,
-        **kwargs,
+        name="paged_window_attention",
     )(pt, idx, qt, kw, vw, wv, pool_k, pool_v)
 
     if store:
-        out, lse, cnt, npk, npv = res
+        npk, npv = _ref.paged_update(pool_k, pool_v, k_win, v_win, pt, idx)
     else:
-        out, lse, cnt = res
         npk, npv = pool_k, pool_v
     out = out[:, :, :S].transpose(0, 2, 1, 3)           # (B, S, Hq, D)
-    lse = lse[:, :, :S]
-    return out, lse, cnt, npk, npv
+    lse = lse[:, :, :S, 0]
+    return out, lse, cnt.reshape(B, 3), npk, npv

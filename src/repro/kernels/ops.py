@@ -1,34 +1,49 @@
 """jit'd wrappers + backend dispatch for the Pallas kernels.
 
-On TPU the Pallas kernels are used (``REPRO_USE_PALLAS=1`` or automatic);
-elsewhere the pure-jnp oracles from ``ref.py`` run — they are the same math
-and XLA/GSPMD handles fusion + partitioning. Tests exercise the kernels in
-interpret mode against the oracles across shape/dtype sweeps.
+The platform alone picks the path: on a TPU the Pallas kernels run
+compiled; elsewhere the pure-jnp oracles from ``ref.py`` run — they are
+the same math and XLA/GSPMD handles fusion + partitioning. Tests steer
+the dispatch themselves (monkeypatching ``_use_pallas``) to run the
+kernels in interpret mode on CPU against the oracles.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import PartitionSpec as PS
 
 from repro.kernels import ref as _ref
 from repro.kernels import flash_attention as _fa
-from repro.kernels import silent_compare as _sc
-from repro.kernels import rmsnorm as _rn
+from repro.sharding.ctx import current_sharder
+from repro.train.fused_xent import shard_map
 
 
 def _use_pallas() -> bool:
-    env = os.environ.get("REPRO_USE_PALLAS")
-    if env is not None:
-        return env not in ("0", "false", "")
     return jax.default_backend() == "tpu"
 
 
 def _pallas_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _per_batch_shard(kernel, *xs):
+    """Run a Pallas kernel on each device's shard of the batch dim.
+
+    GSPMD cannot partition a Mosaic call, so under a multi-device mesh
+    (the active sharding context) the kernel runs inside a shard_map over
+    the context's batch axes — or on the whole batch on every device when
+    the batch does not divide them."""
+    sharder = current_sharder()
+    if sharder is None or sharder.mesh.size == 1:
+        return kernel(*xs)
+    axes = sharder.batch_axes
+    n = int(np.prod([sharder.mesh.shape[a] for a in axes]))
+    spec = PS(axes) if xs[0].shape[0] % n == 0 else PS()
+    return shard_map(kernel, sharder.mesh, (spec,) * len(xs), spec)(*xs)
 
 
 # ----------------------------------------------------------------------
@@ -45,8 +60,9 @@ def attention(q, k, v, *, causal: bool = True, q_offset=0,
     if (kv_len is None and kv_valid is None
             and isinstance(q_offset, int) and q_offset == 0):
         if _use_pallas() and sq >= 8:
-            return _fa.flash_attention(q, k, v, causal=causal,
-                                       interpret=_pallas_interpret())
+            return _per_batch_shard(partial(
+                _fa.flash_attention, causal=causal,
+                interpret=_pallas_interpret()), q, k, v)
         if skv >= FLASH_THRESHOLD:
             from repro.kernels.flash_xla import flash_xla
             return flash_xla(q, k, v, causal, 0)
@@ -123,34 +139,9 @@ def paged_window(q, k_win, v_win, pool_k, pool_v, pt, idx, *,
     return out, ck, cv, (cnt if counters else None)
 
 
-def flash_attention(q, k, v, *, causal: bool = True, interpret=None,
-                    block_q: int = 128, block_k: int = 128) -> jax.Array:
-    if interpret is None:
-        interpret = _pallas_interpret()
-    return _fa.flash_attention(q, k, v, causal=causal, interpret=interpret,
-                               block_q=block_q, block_k=block_k)
-
-
-@partial(jax.jit, static_argnames=("tol", "use_pallas"))
-def silent_fraction(a, b, tol: float = 0.01, use_pallas: bool = False):
+@partial(jax.jit, static_argnames=("tol",))
+def silent_fraction(a, b, tol: float = 0.01):
     """Fraction of silent (unchanged within tol) elements between a and b."""
     n = a.size
-    if use_pallas:
-        cnt = _sc.silent_compare(a, b, tol, interpret=_pallas_interpret())
-    else:
-        cnt = _ref.silent_compare_ref(a, b, tol)
+    cnt = _ref.silent_compare_ref(a, b, tol)
     return cnt.astype(jnp.float32) / max(n, 1)
-
-
-def silent_count(a, b, tol: float = 0.01, use_pallas: Optional[bool] = None):
-    if use_pallas is None:
-        use_pallas = _use_pallas()
-    if use_pallas:
-        return _sc.silent_compare(a, b, tol, interpret=_pallas_interpret())
-    return _ref.silent_compare_ref(a, b, tol)
-
-
-def rmsnorm(x, scale, eps: float = 1e-5):
-    if _use_pallas():
-        return _rn.rmsnorm(x, scale, eps, interpret=_pallas_interpret())
-    return _ref.rmsnorm_ref(x, scale, eps)

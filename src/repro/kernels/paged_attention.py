@@ -7,7 +7,7 @@ step's BlockSpec index map chases ``pt[b, m]`` directly, so the
 (B, M*page) logical view the ref path materializes in HBM
 (``ref.paged_gather``) never exists. The new token's K/V row is spliced
 into its page block in VMEM (the pool scatter itself stays a cheap
-O(B*Hkv*D) host-side ``ref.paged_update`` — one row per slot).
+O(B*Hkv*D) ``ref.paged_update`` outside the kernel — one row per slot).
 
 Waste counters (the machine-code tier of the detector stack, see
 DESIGN.md § Kernel tier): at the splice step — the store site of the
@@ -21,13 +21,16 @@ emits per-slot element counts [stored, silent, dropped]:
   * dropped — elements whose target page is unmapped (the store is
               masked off: dead lanes).
 
-Grid iteration order is (B, Hq, M) with the page dim innermost; flash
-accumulators live in VMEM scratch across the page sweep. All grid dims
+Grid iteration order is (B, M) with the page dim innermost; one grid
+step covers every head of a slot, so each block's last two dims are the
+full (Hkv, D) / (G, D) head extents the TPU tiling accepts. Flash
+accumulators live in VMEM scratch across the page sweep; both grid dims
 are "arbitrary" (scratch carries state), so revisiting semantics match
 interpret mode.
 
 Validated in interpret mode on CPU against the ref composition
-``paged_update -> paged_gather -> attention_ref``.
+``paged_update -> paged_gather -> attention_ref``, and on a TPU by
+``chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -40,78 +43,74 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.events import silent_mask
-from repro.kernels.flash_attention import online_softmax_step
+from repro.kernels.flash_attention import NEG_INF, online_softmax_update
 
-NEG_INF = -1e30
+
+def counter_row(stored, silent, dropped):
+    """(1, 1, 3) int32 [stored, silent, dropped] block from three scalars
+    (vector selects: the TPU has no scalar stores into VMEM)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 3), 2)
+    return jnp.where(lane == 0, stored, jnp.where(lane == 1, silent, dropped))
 
 
 def _decode_kernel(pt_ref, idx_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
                    o_ref, lse_ref, cnt_ref,
-                   m_scr, l_scr, acc_scr, cnt_scr, *,
-                   scale: float, ps: int, G: int, tol: float):
+                   m_scr, l_scr, acc_scr, *,
+                   scale: float, ps: int, Hkv: int, tol: float):
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    m = pl.program_id(2)
-    nm = pl.num_programs(2)
+    m = pl.program_id(1)
+    nm = pl.num_programs(1)
     idx = idx_ref[b]
     page = pt_ref[b, m]
-
-    @pl.when((h == 0) & (m == 0))
-    def _zero_cnt():
-        cnt_scr[...] = jnp.zeros_like(cnt_scr)
 
     @pl.when(m == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    offs = jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
-    pos = m * ps + offs                                   # (ps, 1) logical
+    pos = m * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
+    is_new = pos == idx                                   # (ps, 1)
 
-    live = (idx >= 0) & (page >= 0) & (m * ps <= idx)
-
-    @pl.when(live)
+    @pl.when((idx >= 0) & (page >= 0) & (m * ps <= idx))
     def _attend():
-        q = q_ref[0].astype(jnp.float32)                  # (1, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)            # (ps, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        is_new = pos == idx                               # (ps, 1)
-        k = jnp.where(is_new, kn_ref[0].astype(jnp.float32), k)
-        v = jnp.where(is_new, vn_ref[0].astype(jnp.float32), v)
-
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * scale                                     # (1, ps)
-        s = jnp.where(pos.T <= idx, s, NEG_INF)
-        online_softmax_step(s, v, m_scr, l_scr, acc_scr)
+        for g in range(Hkv):
+            q = q_ref[0, g].astype(jnp.float32)           # (G, D)
+            k = jnp.where(is_new, kn_ref[0, g:g + 1].astype(jnp.float32),
+                          k_ref[0, :, g].astype(jnp.float32))   # (ps, D)
+            v = jnp.where(is_new, vn_ref[0, g:g + 1].astype(jnp.float32),
+                          v_ref[0, :, g].astype(jnp.float32))
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(pos.T <= idx, s * scale, NEG_INF)     # (G, ps)
+            m_scr[g], l_scr[g], acc_scr[g] = online_softmax_update(
+                s, v, m_scr[g], l_scr[g], acc_scr[g])
 
     # --- store-site counters: the new row lands in page idx // ps ------
-    D = q_ref.shape[-1]
+    # (a target past the table is counted at the last step, as dropped)
+    tgt = idx // ps
 
-    @pl.when((h % G == 0) & (idx >= 0) & (m == idx // ps))
+    @pl.when((idx >= 0) & ((m == tgt) | ((m == nm - 1) & (tgt >= nm))))
     def _count():
-        pdt = k_ref.dtype
-        old_k = k_ref[0, :, 0].astype(jnp.float32)        # pre-store content
-        old_v = v_ref[0, :, 0].astype(jnp.float32)
-        new_k = kn_ref[0].astype(pdt).astype(jnp.float32)
-        new_v = vn_ref[0].astype(pdt).astype(jnp.float32)
-        row = pos == idx                                  # (ps, 1)
-        sil = (jnp.sum(jnp.where(row, silent_mask(old_k, new_k, tol), False),
-                       dtype=jnp.int32)
-               + jnp.sum(jnp.where(row, silent_mask(old_v, new_v, tol), False),
-                         dtype=jnp.int32))
-        ok = page >= 0
-        cnt_scr[0, 0] += jnp.where(ok, 2 * D, 0)
-        cnt_scr[0, 1] += jnp.where(ok, sil, 0)
-        cnt_scr[0, 2] += jnp.where(ok, 0, 2 * D)
-
-    cnt_ref[...] = cnt_scr[...]
+        D = k_ref.shape[-1]
+        sil = jnp.zeros((), jnp.int32)
+        for g in range(Hkv):
+            for new_ref, old_ref in ((kn_ref, k_ref), (vn_ref, v_ref)):
+                old = old_ref[0, :, g].astype(jnp.float32)   # pre-store
+                new = new_ref[0, g:g + 1].astype(jnp.float32)
+                hit = silent_mask(old, new, tol) & is_new
+                sil += jnp.sum(jnp.where(hit, 1, 0), dtype=jnp.int32)
+        ok = (tgt < nm) & (page >= 0)
+        full = 2 * Hkv * D
+        cnt_ref[...] = counter_row(jnp.where(ok, full, 0),
+                                   jnp.where(ok, sil, 0),
+                                   jnp.where(ok, 0, full))
 
     @pl.when(m == nm - 1)
     def _fin():
         l = l_scr[...]
-        lse_ref[...] = jnp.where(l > 0.0, m_scr[...] + jnp.log(
+        lse_ref[0] = jnp.where(l > 0.0, m_scr[...] + jnp.log(
             jnp.where(l > 0.0, l, 1.0)), NEG_INF)
         l = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
@@ -142,7 +141,7 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
 
     pt = pt.astype(jnp.int32)
     idx = idx.astype(jnp.int32)
-    q2 = q.reshape(B, Hq, D)
+    q4 = q.reshape(B, Hkv, G, D)
     # round-trip the new row through the pool dtype: the ref path attends
     # the value the pool actually stores, so the splice must match it bit
     # for bit (e.g. bf16 pools under f32 activations)
@@ -150,47 +149,48 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     kn = k_new.reshape(B, Hkv, D).astype(pdt)
     vn = v_new.reshape(B, Hkv, D).astype(pdt)
 
-    def q_index(b, h, m, pt_ref, idx_ref):
-        return (b, h, 0)
+    def slot_index(b, m, pt_ref, idx_ref):
+        return (b, 0, 0, 0)
 
-    def new_index(b, h, m, pt_ref, idx_ref):
-        return (b, h // G, 0)
+    def new_index(b, m, pt_ref, idx_ref):
+        return (b, 0, 0)
 
-    def pool_index(b, h, m, pt_ref, idx_ref):
-        return (jnp.clip(pt_ref[b, m], 0, P - 1), 0, h // G, 0)
+    def pool_index(b, m, pt_ref, idx_ref):
+        return (jnp.clip(pt_ref[b, m], 0, P - 1), 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hq, M),
+        grid=(B, M),
         in_specs=[
-            pl.BlockSpec((1, 1, D), q_index),
-            pl.BlockSpec((1, 1, D), new_index),
-            pl.BlockSpec((1, 1, D), new_index),
-            pl.BlockSpec((1, ps, 1, D), pool_index),
-            pl.BlockSpec((1, ps, 1, D), pool_index),
+            pl.BlockSpec((1, Hkv, G, D), slot_index),
+            pl.BlockSpec((1, Hkv, D), new_index),
+            pl.BlockSpec((1, Hkv, D), new_index),
+            pl.BlockSpec((1, ps, Hkv, D), pool_index),
+            pl.BlockSpec((1, ps, Hkv, D), pool_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, D), q_index),
-            pl.BlockSpec((1, 1), lambda b, h, m, *_: (b, h)),
-            pl.BlockSpec((1, 3), lambda b, h, m, *_: (b, 0)),
+            pl.BlockSpec((1, Hkv, G, D), slot_index),
+            pl.BlockSpec((1, Hkv, G, 1), slot_index),
+            pl.BlockSpec((1, 1, 3), new_index),
         ],
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),      # running max
-            pltpu.VMEM((1, 1), jnp.float32),      # running denom
-            pltpu.VMEM((1, D), jnp.float32),      # accumulator
-            pltpu.VMEM((1, 3), jnp.int32),        # waste counters
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),      # running max
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),      # running denom
+            pltpu.VMEM((Hkv, G, D), jnp.float32),      # accumulator
         ],
     )
     out, lse, cnt = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, ps=ps, G=G, tol=tol),
+        functools.partial(_decode_kernel, scale=scale, ps=ps, Hkv=Hkv,
+                          tol=tol),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-            jax.ShapeDtypeStruct((B, Hq), jnp.float32),
-            jax.ShapeDtypeStruct((B, 3), jnp.int32),
+            jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+            jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, 3), jnp.int32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(pt, idx, q2, kn, vn, pool_k, pool_v)
-    return out.reshape(B, 1, Hq, D), lse, cnt
+        name="paged_decode_attention",
+    )(pt, idx, q4, kn, vn, pool_k, pool_v)
+    return out.reshape(B, 1, Hq, D), lse.reshape(B, Hq), cnt.reshape(B, 3)

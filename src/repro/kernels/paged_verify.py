@@ -3,7 +3,7 @@
 A thin mode wrapper over the paged window kernel
 (``kernels/flash_prefill.py``): verify pushes the k+1-token draft
 window against the paged pool exactly like prefill pushes a prompt
-chunk — same in-kernel page-table gather, same store epilogue — the
+chunk — same in-kernel page-table gather, same store-site counters — the
 only degree of freedom is what happens to the pool:
 
   * ``mode="overwrite"`` (``LM.verify(commit=True)``): all k+1 window

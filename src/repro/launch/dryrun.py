@@ -4,6 +4,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # --- everything below may import jax -----------------------------------
 import argparse          # noqa: E402
 import json              # noqa: E402
+import sys               # noqa: E402
 import time              # noqa: E402
 import traceback         # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -23,6 +24,7 @@ from repro.sharding.rules import make_strategy        # noqa: E402
 from repro.train import state as TS                   # noqa: E402
 from repro.train.step import make_train_step          # noqa: E402
 from repro.configs.base import TrainConfig            # noqa: E402
+from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 
@@ -32,10 +34,7 @@ _SERVE_WG_BYTES = 12e9
 
 
 def _mem_summary(compiled) -> dict:
-    try:
-        ma = compiled.memory_analysis()
-    except Exception:
-        return {}
+    ma = compiled.memory_analysis()
     if ma is None:
         return {}
     keys = ("argument_size_in_bytes", "output_size_in_bytes",
@@ -178,6 +177,7 @@ def main() -> None:
     ap.add_argument("--tag", default="baseline")
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     archs = registry.ARCH_IDS if args.arch == "all" else args.arch.split(",")
     shapes = (list(SHAPES_BY_NAME) if args.shape == "all"
@@ -217,6 +217,8 @@ def main() -> None:
                 results.append(rec)
     n_ok = sum(r.get("status") == "ok" for r in results)
     print(f"done: {n_ok} ok / {len(results)} attempted", flush=True)
+    if any(r.get("status") == "error" for r in results):
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -43,6 +43,7 @@ from repro.serve.decode import StepCache
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.router import FleetRouter
 from repro.serve.workload import Trace, make_trace
+from repro.runtime.compile_cache import enable_compile_cache
 
 # Default smoke workload: spaced poisson arrivals with a long shared
 # prefix. Spacing keeps owner-side queueing out of the picture, so the
@@ -293,6 +294,7 @@ def main():
                     help="content-addressed dedup of same-burst "
                          "duplicate prefixes (router + engine)")
     a = ap.parse_args()
+    enable_compile_cache()
     run(a.arch, smoke=a.smoke, replicas=a.replicas, slots=a.slots,
         policy=a.policy, page_size=a.page_size, num_pages=a.num_pages,
         requests=a.requests, prompt_len=a.prompt_len,

@@ -44,6 +44,7 @@ from repro.serve.decode import (make_engine_prefill, make_engine_tick,
 from repro.serve.engine import ENGINE_FAMILIES
 from repro.train import state as TS
 from repro.train.step import make_train_step
+from repro.runtime.compile_cache import enable_compile_cache
 
 BASELINE_VERSION = 1
 
@@ -266,6 +267,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="rewrite --baseline from current findings")
     ap.add_argument("-v", "--verbose", action="store_true")
     a = ap.parse_args(argv)
+    enable_compile_cache()
     archs = registry.ARCH_IDS if a.all_configs else [a.config]
     subjects = tuple(s for s in a.subjects.split(",") if s)
     _, code = run(archs, smoke=not a.full_size, subjects=subjects,

@@ -62,6 +62,7 @@ from repro.models.zoo import build_model
 from repro.serve.engine import ENGINE_FAMILIES, Request, ServeEngine
 from repro.train import state as TS
 from repro.train.step import make_train_step
+from repro.runtime.compile_cache import enable_compile_cache
 
 SCHEMA = 1
 
@@ -378,6 +379,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "value is 0.0)")
     ap.add_argument("--top-k", type=int, default=15)
     a = ap.parse_args(argv)
+    enable_compile_cache()
 
     configs = ([s for s in a.configs.split(",") if s] if a.configs
                else list(registry.ARCH_IDS))

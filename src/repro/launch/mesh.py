@@ -8,17 +8,25 @@ import to obtain the placeholder devices.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """Mesh whose axes GSPMD partitions automatically (``make_mesh``
+    defaults to explicit sharding-in-types axes)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
-    """Single-device mesh for CPU smoke/examples (data=1, model=1)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    """(data, model) mesh over this host's devices: data = all of them,
+    model = 1 (a single-device mesh for CPU smoke/examples)."""
+    return _auto_mesh((len(jax.devices()), 1), ("data", "model"))
 
 
 def make_elastic_mesh(num_devices: int):
@@ -27,4 +35,4 @@ def make_elastic_mesh(num_devices: int):
     model = 16
     while model > 1 and num_devices % model:
         model //= 2
-    return jax.make_mesh((num_devices // model, model), ("data", "model"))
+    return _auto_mesh((num_devices // model, model), ("data", "model"))

@@ -49,6 +49,7 @@ from repro.models.zoo import build_model
 from repro.serve.decode import make_serve_step
 from repro.serve.engine import ENGINE_FAMILIES, Request, ServeEngine
 from repro.serve.spec import make_drafter
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def padding_waste_profile(stats) -> WasteProfile:
@@ -385,6 +386,7 @@ def main():
                          "to cfg.encoder_frames (outputs identical; "
                          "prefill_padding bytes drop)")
     a = ap.parse_args()
+    enable_compile_cache()
     run(a.arch, smoke=a.smoke, batch=a.batch, prompt_len=a.prompt_len,
         gen=a.gen, profile=a.profile, profile_out=a.profile_out,
         sarif_out=a.sarif_out,

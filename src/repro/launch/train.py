@@ -36,6 +36,31 @@ from repro.runtime.fault import FleetMonitor
 from repro.sharding.rules import make_strategy
 from repro.train import state as TS
 from repro.train.step import make_train_step
+from repro.runtime.compile_cache import enable_compile_cache
+
+
+def jit_train_step(model, tc: TrainConfig, strategy=None, *,
+                   donate: bool = True):
+    """The jitted train step and the host-batch -> device placement that
+    goes with it. Under a sharding ``strategy`` the state keeps its
+    `TS.state_shardings` across steps and the batch is split over the
+    strategy's batch axes; without one everything sits on the default
+    device."""
+    step_fn = make_train_step(model, tc, strategy)
+    donate_argnums = (0,) if donate else ()
+    if strategy is None:
+        return (jax.jit(step_fn, donate_argnums=donate_argnums),
+                lambda b: {k: jnp.asarray(v) for k, v in b.items()})
+    jit_step = jax.jit(step_fn, donate_argnums=donate_argnums,
+                       out_shardings=(TS.state_shardings(model, strategy),
+                                      None))
+    batch_sharding = strategy.named(
+        jax.sharding.PartitionSpec(strategy.batch_axes))
+
+    def to_device(b):
+        return {k: jax.device_put(jnp.asarray(v), batch_sharding)
+                for k, v in b.items()}
+    return jit_step, to_device
 
 
 def run(arch: str, *, smoke: bool = True, steps: int = 50, batch: int = 8,
@@ -57,21 +82,14 @@ def run(arch: str, *, smoke: bool = True, steps: int = 50, batch: int = 8,
                      warmup_steps=max(horizon // 10, 1),
                      microbatches=microbatches, remat=remat, seed=seed)
 
-    mesh = None
-    strat = None
-    if strategy:
-        mesh = make_host_mesh() if len(jax.devices()) == 1 else None
-        if mesh is not None:
-            strat = make_strategy(strategy, mesh)
-
-    step_fn = make_train_step(model, tc, strat)
+    strat = make_strategy(strategy, make_host_mesh()) if strategy else None
     # Tier-3 detectors hold pre-step params across the call -> no donation
-    donate = () if profile else (0,)
-    jit_step = jax.jit(step_fn, donate_argnums=donate)
+    jit_step, to_device = jit_train_step(model, tc, strat,
+                                         donate=not profile)
 
     obj_registry = ObjectRegistry() if objects else None
     state = TS.create(model, jax.random.PRNGKey(seed),
-                      registry=obj_registry)
+                      registry=obj_registry, strategy=strat)
     obj_scan = None
     if obj_registry is not None:
         # scan AT INIT: the moments are all bit-identical zeros here —
@@ -97,7 +115,7 @@ def run(arch: str, *, smoke: bool = True, steps: int = 50, batch: int = 8,
     tier2_profile = None
     if waste_report:
         b0 = next(iter(data))
-        lowered = jit_step.lower(state, {k: jnp.asarray(v) for k, v in b0.items()})
+        lowered = jit_step.lower(state, to_device(b0))
         rep = analyze_waste(lowered.compile().as_text())
         print(rep.summary())
         tier2_profile = rep.profile
@@ -106,7 +124,7 @@ def run(arch: str, *, smoke: bool = True, steps: int = 50, batch: int = 8,
     t_start = time.time()
     for step in range(start_step, steps):
         b = next(data)
-        batch_dev = {k: jnp.asarray(v) for k, v in b.items()}
+        batch_dev = to_device(b)
         if detectors:
             detectors.on_batch(step, b)
             params_before = state.params
@@ -173,6 +191,7 @@ def main():
     ap.add_argument("--sarif-out", default=None,
                     help="write the merged waste profile as SARIF 2.1.0")
     a = ap.parse_args()
+    enable_compile_cache()
     run(a.arch, smoke=a.smoke, steps=a.steps, batch=a.batch, seq=a.seq,
         lr=a.lr, ckpt_dir=a.ckpt_dir, ckpt_every=a.ckpt_every,
         profile=a.profile, waste_report=a.waste_report, resume=a.resume,

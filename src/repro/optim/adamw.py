@@ -21,19 +21,13 @@ class AdamWState(NamedTuple):
     v: Any
 
 
-def init(params: Any, registry=None, owner: str = "opt") -> AdamWState:
-    """Zero moments. With an `ObjectRegistry` (core/objects.py) every
-    moment leaf registers as a live ``opt_state`` object — all
-    bit-identical zeros at init, which is exactly the replica-detector
-    demo: state that could lazy-materialize on first non-zero update."""
+def init(params: Any) -> AdamWState:
+    """Zero moments (all bit-identical zeros at init: `train/state.py`
+    registers them as ``opt_state`` objects, the replica-detector demo of
+    state that could lazy-materialize on first non-zero update)."""
     zeros = lambda p: jnp.zeros_like(p, dtype=jnp.float32)
-    state = AdamWState(m=jax.tree_util.tree_map(zeros, params),
-                       v=jax.tree_util.tree_map(zeros, params))
-    if registry is not None:
-        from repro.core.objects import register_tree
-        register_tree(registry, f"{owner}/m", state.m, kind="opt_state")
-        register_tree(registry, f"{owner}/v", state.v, kind="opt_state")
-    return state
+    return AdamWState(m=jax.tree_util.tree_map(zeros, params),
+                      v=jax.tree_util.tree_map(zeros, params))
 
 
 def update(tc: TrainConfig, grads: Any, state: AdamWState, master: Any,

@@ -743,3 +743,12 @@ class ServeEngine:
         active = jnp.ones((self.num_slots,), bool)
         return self._tick_fn.lower(self.params, self.cache, self.tokens,
                                    active)
+
+    def lowered_prefill(self, prompt_len: int):
+        """Lowered admission prefill at one padded prompt length."""
+        B = self.num_slots
+        zeros = jnp.zeros((B,), jnp.int32)
+        return self._prefill_fn.lower(
+            self.params, self.cache, jnp.zeros((B, prompt_len), jnp.int32),
+            jnp.ones((B,), bool), zeros,
+            jnp.full((B,), prompt_len, jnp.int32), self.tokens)

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as PS
 
-from repro.train.fused_xent import shard_map  # version-compat wrapper
+from repro.train.fused_xent import shard_map
 
 
 def _axis_index(names: Tuple[str, ...], mesh) -> jax.Array:
@@ -250,8 +250,8 @@ def verify_paged_attention_sharded(q, k_new, v_new, ck, cv, pt, idx, *,
 
         if use_pallas:
             # Pallas fast path: the fused window kernel on the LOCALIZED
-            # page table does the whole shard body — its store epilogue
-            # writes exactly the window rows whose pages this shard owns
+            # page table does the whole shard body — its store writes
+            # exactly the window rows whose pages this shard owns
             # (store-mode window validity = "target page mapped", which
             # under the localized table means locally owned, so every
             # window row is attended and stored by exactly one shard),

@@ -20,18 +20,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as PS
 
-try:                                   # jax>=0.6
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except ImportError:                    # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _plain(x, w, labels, z_loss):

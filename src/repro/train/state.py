@@ -17,20 +17,34 @@ class TrainState(NamedTuple):
 
 
 def create(model, key, compute_dtype=jnp.bfloat16,
-           registry=None) -> TrainState:
+           registry=None, strategy=None) -> TrainState:
     """With an object registry (core/objects.py) the compute/master
-    trees register as ``param`` objects here and the moments inside
-    `adamw.init` — so replica findings carry each tree's real
-    allocation site."""
-    master = model.init(key, dtype=jnp.float32)
-    params = jax.tree_util.tree_map(lambda p: p.astype(compute_dtype), master)
+    trees register as ``param`` objects and the moments as
+    ``opt_state`` objects here, where they are allocated.
+
+    With a sharding ``strategy`` the state is created sharded: one jit
+    whose ``out_shardings`` are `state_shardings`, so no device ever
+    holds the whole state (14 B/param would not fit one chip)."""
+    def build(key):
+        master = model.init(key, dtype=jnp.float32)
+        return TrainState(
+            params=jax.tree_util.tree_map(
+                lambda p: p.astype(compute_dtype), master),
+            master=master, opt=adamw.init(master),
+            step=jnp.zeros((), jnp.int32))
+
+    if strategy is None:
+        state = build(key)
+    else:
+        state = jax.jit(build, out_shardings=state_shardings(
+            model, strategy))(key)
     if registry is not None:
         from repro.core.objects import register_tree
-        register_tree(registry, "train/master", master, kind="param")
-        register_tree(registry, "train/params", params, kind="param")
-    return TrainState(params=params, master=master,
-                      opt=adamw.init(master, registry=registry),
-                      step=jnp.zeros((), jnp.int32))
+        register_tree(registry, "train/master", state.master, kind="param")
+        register_tree(registry, "train/params", state.params, kind="param")
+        register_tree(registry, "opt/m", state.opt.m, kind="opt_state")
+        register_tree(registry, "opt/v", state.opt.v, kind="opt_state")
+    return state
 
 
 def abstract(model, compute_dtype=jnp.bfloat16) -> TrainState:
@@ -51,3 +65,11 @@ def state_specs(model, strategy):
     return TrainState(params=p_specs, master=o_specs,
                       opt=adamw.AdamWState(m=o_specs, v=o_specs),
                       step=shd.PartitionSpec())
+
+
+def state_shardings(model, strategy):
+    """NamedSharding tree matching TrainState on the strategy's mesh."""
+    import jax.sharding as shd
+    return jax.tree_util.tree_map(
+        strategy.named, state_specs(model, strategy),
+        is_leaf=lambda x: isinstance(x, shd.PartitionSpec))

@@ -73,3 +73,23 @@ def test_jit_and_vmap_compose():
     want = ref.attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("Sq,causal", [(100, True), (64, False)])
+def test_grad_matches_reference(Sq, causal):
+    """The custom VJP (Pallas forward lse + flash_xla backward) gives the
+    reference attention's gradients."""
+    q, k, v = _qkv(2, Sq, Sq, 4, 2, 16, jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+    got = jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, interpret=True, block_q=32, block_k=32)),
+        argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: ref.attention_ref(
+        q, k, v, causal=causal)), argnums=(0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   atol=1e-4, rtol=1e-4)

@@ -264,3 +264,23 @@ def test_checked_counters_populate_fractions():
     prof = lint_fn(f, jnp.zeros(17), jnp.ones(5), subject="t")
     fr = prof.fractions()
     assert fr["dead_store"] == 0.5                    # 1 of 2 store sites
+
+
+# --------------------------------------------------------------- provenance
+def test_user_frames_are_never_empty_for_traced_eqns():
+    """Every eqn traced from user code carries its user frames: the C1
+    context of a tier-0 finding starts at this file, not at the bare
+    primitive name."""
+    from repro.core.context import context_of_eqn, user_frames
+
+    def f(x):
+        return jnp.sin(x) * 2.0
+
+    jaxpr = jax.make_jaxpr(f)(jnp.ones(3)).jaxpr
+    assert jaxpr.eqns
+    for eqn in jaxpr.eqns:
+        frames = user_frames(eqn)
+        assert frames, f"no user frames for {eqn.primitive.name}"
+        assert os.path.basename(frames[0].file_name) == HERE
+        ctx = context_of_eqn(eqn)
+        assert len(ctx) > 1 and ctx[-1] == eqn.primitive.name

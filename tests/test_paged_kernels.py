@@ -214,10 +214,10 @@ def test_ops_dispatch_parity(monkeypatch):
     pk, pv = _pools(jnp.float32)
     q, kn, vn = _rows(1)
     pt, idx = jnp.asarray(HOSTILE_PT), jnp.asarray(HOSTILE_IDX)
-    monkeypatch.setenv("REPRO_USE_PALLAS", "0")
+    monkeypatch.setattr(kops, "_use_pallas", lambda: False)
     o_r, ck_r, cv_r, c_r = kops.paged_decode(q, kn, vn, pk, pv, pt, idx,
                                              counters=True)
-    monkeypatch.setenv("REPRO_USE_PALLAS", "1")
+    monkeypatch.setattr(kops, "_use_pallas", lambda: True)
     o_p, ck_p, cv_p, c_p = kops.paged_decode(q, kn, vn, pk, pv, pt, idx,
                                              counters=True)
     live = np.asarray(idx) >= 0
@@ -336,11 +336,11 @@ class GarbageDrafter:
 
 def test_engine_greedy_identical_dense_paged_pallas(monkeypatch):
     cfg, model, params = _smoke_model()
-    monkeypatch.setenv("REPRO_USE_PALLAS", "0")
+    monkeypatch.setattr(kops, "_use_pallas", lambda: False)
     dense, _ = _serve(model, params, cfg, kv="dense")
     paged, _ = _serve(model, params, cfg, kv="paged")
     assert dense == paged
-    monkeypatch.setenv("REPRO_USE_PALLAS", "1")
+    monkeypatch.setattr(kops, "_use_pallas", lambda: True)
     pallas, _ = _serve(model, params, cfg, kv="paged")
     assert pallas == dense
 
@@ -392,6 +392,7 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
+from repro.kernels import ops
 from repro.serve import flash_decode as fd
 
 mesh = Mesh(np.array(jax.devices()).reshape(2), ("model",))
@@ -413,10 +414,10 @@ for dtype in (jnp.float32, jnp.bfloat16):
     for entry, a in ((fd.decode_paged_attention_sharded, (q, kn, vn)),
                      (fd.verify_paged_attention_sharded, (qw, kw, vw))):
         with mesh:
-            os.environ["REPRO_USE_PALLAS"] = "0"
+            ops._use_pallas = lambda: False
             o_r, ck_r, cv_r = entry(*a, pool_k, pool_v, pt, idx, mesh=mesh,
                                     batch_axes=(), seq_axes=("model",))
-            os.environ["REPRO_USE_PALLAS"] = "1"
+            ops._use_pallas = lambda: True
             o_p, ck_p, cv_p = entry(*a, pool_k, pool_v, pt, idx, mesh=mesh,
                                     batch_axes=(), seq_axes=("model",))
         np.testing.assert_array_equal(np.asarray(ck_r), np.asarray(ck_p))
@@ -433,7 +434,6 @@ def test_sharded_pallas_matches_ref_subprocess():
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
     env["JAX_PLATFORMS"] = "cpu"
-    env.pop("REPRO_USE_PALLAS", None)
     out = subprocess.run([sys.executable, "-c", _SUBPROC], env=env,
                          capture_output=True, text=True, timeout=420)
     assert "SUBPROC_OK" in out.stdout, out.stderr[-3000:]

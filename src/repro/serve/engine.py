@@ -42,6 +42,20 @@ The jitted tick/prefill come from `serve.decode`'s step factories
 engine needs every sub-block to carry an indexed KV cache, so it
 supports the "dense" and "moe" families; other families are served by
 the legacy token-loop in `launch/serve.py`.
+
+Each `step()` is cut into phases (`ServeEngine._phase`). A phase opens
+the profiler span ``serve.<phase>`` (`jax.profiler.TraceAnnotation`:
+inert unless a profiler session runs, and then on the device trace's
+clock) and adds its host seconds to ``stats["<phase>_s"]``:
+
+  * ``serve.step`` — all of `step()`; also ``steps`` and ``step_cpu_s``;
+  * ``serve.admit`` — admission up to the prefill call;
+  * ``serve.prefill`` — the jitted prefill and its wait;
+  * ``serve.draft`` — the drafter's proposals (speculative only);
+  * ``serve.decode`` — the jitted tick or verify and its wait;
+  * ``serve.accept`` — everything after a device call returns;
+  * ``serve.jxperf`` — each call made for JXPerf (the detectors' hooks
+    and the kernel-counter reads), nested in the phase that makes it.
 """
 from __future__ import annotations
 
@@ -53,6 +67,7 @@ from typing import Any, Deque, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.detectors import ServingDetectors, SlotWrite, VerifyWrite
 from repro.serve.decode import (make_engine_prefill, make_engine_tick,
@@ -106,6 +121,27 @@ class MonotonicStats(dict):
                 f"engine stat {key!r} may not decrease ({cur} -> {value}); "
                 f"fleet aggregation reads monotonic snapshots")
         super().__setitem__(key, value)
+
+
+class _Phase:
+    """One phase of a `ServeEngine` step: the profiler span ``span``
+    while it runs, and its host seconds added to each ``stats[key]``."""
+    __slots__ = ("_stats", "_keys", "_span", "_t0")
+
+    def __init__(self, stats, span: str, keys, args):
+        self._stats = stats
+        self._keys = keys
+        self._span = TraceAnnotation(span, **args)
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        for key in self._keys:
+            self._stats[key] += dt
+        self._span.__exit__(*exc)
 
 
 def _bucket(n: int, lo: int = 8) -> int:
@@ -242,7 +278,15 @@ class ServeEngine:
              # speculative decode accounting
              "spec_ticks": 0, "draft_proposed": 0,
              "draft_accepted": 0, "draft_s": 0.0,
-             "verify_s": 0.0, "verified_positions": 0})
+             "verify_s": 0.0, "verified_positions": 0,
+             # host seconds per step phase (module docstring); prefill_s,
+             # decode_s, draft_s and verify_s above are phases too.
+             # step_s holds all the others; jxperf_s also counts in the
+             # phase that made the call. step_cpu_s is the stepping
+             # thread's CPU time over step_s: below it, the host waited
+             # (on the device, or frozen)
+             "steps": 0, "step_s": 0.0, "step_cpu_s": 0.0,
+             "admit_s": 0.0, "accept_s": 0.0, "jxperf_s": 0.0})
 
         if step_cache is not None:
             assert step_cache.model is model, \
@@ -276,6 +320,13 @@ class ServeEngine:
                 row_elems={n: 2 * int(np.prod(main[n]["k"].shape[3:]))
                            for n in self._kv_names})
             self._peek_fn = jax.jit(self._make_peek())
+
+    def _phase(self, span: str, *keys: str, **args) -> _Phase:
+        """``with self._phase("serve.admit", "admit_s"):`` — the profiler
+        span ``span`` and the phase's host seconds added to each ``stats``
+        key. ``args`` annotate the span: plain ints, since they are
+        evaluated whether or not a profiler runs."""
+        return _Phase(self.stats, span, keys, args)
 
     # ---------------------------- jitted steps ------------------------
     def _make_peek(self):
@@ -320,18 +371,20 @@ class ServeEngine:
     def _read_kernel_counts(self):
         """The last jitted forward's in-kernel [stored, silent, dropped]
         element counts, per KV sub-block, as (L, B, 3) host arrays —
-        or None when the kernel tier is off / unobserved."""
-        if not self.kernel_counters or self.detectors is None:
-            return None
+        or None when unobserved. Read only with the kernel tier on
+        (`kernel_counters` and detectors attached)."""
         counts = self.model.kernel_counters(self.cache)
         if counts is None:
             return None
         return {n: np.asarray(c) for n, c in counts.items()}
 
     def _emit_kernel_store(self, site: str) -> None:
-        counts = self._read_kernel_counts()
-        if counts is not None:
-            self.detectors.on_kernel_store(self.step_no, site, counts)
+        if not self.kernel_counters or self.detectors is None:
+            return
+        with self._phase("serve.jxperf", "jxperf_s"):
+            counts = self._read_kernel_counts()
+            if counts is not None:
+                self.detectors.on_kernel_store(self.step_no, site, counts)
 
     # ------------------------------ schedule ---------------------------
     def submit(self, req: Request) -> None:
@@ -357,7 +410,8 @@ class ServeEngine:
         AND disarm the detectors' now-stale traps on them."""
         self.stats["pages_freed"] += len(freed)
         if self.detectors is not None and freed:
-            self.detectors.on_page_free(freed)
+            with self._phase("serve.jxperf", "jxperf_s"):
+                self.detectors.on_page_free(freed)
 
     def note_freed(self, freed: List[int]) -> None:
         """Pages freed by an EXTERNAL holder of this replica's pool —
@@ -389,7 +443,8 @@ class ServeEngine:
                 # fresh table before its prefill).
                 self._note_freed(self.kv.free_slot(slot))
             if self.detectors is not None:
-                self.detectors.on_finish(self.step_no, slot, req.rid)
+                with self._phase("serve.jxperf", "jxperf_s"):
+                    self.detectors.on_finish(self.step_no, slot, req.rid)
 
     def _dedup_group(self, group: List[Request]) -> List[Request]:
         """Content-addressed same-burst dedup (OJXPerf replica fix).
@@ -430,116 +485,125 @@ class ServeEngine:
         return keep
 
     def _admit(self) -> None:
-        free = [b for b, r in enumerate(self.slots) if r is None]
-        group: List[Request] = []
-        while free[len(group):] and self._queue \
-                and self._queue[0].arrival <= self.step_no:
-            group.append(self._queue.popleft())
-        if self.content_dedup and len(group) > 1:
-            group = self._dedup_group(group)
-        if not group:
-            return
-        B = self.num_slots
-        admit = np.zeros(B, bool)
-        starts = np.zeros(B, np.int32)
-        lengths = np.ones(B, np.int32)
-        taken: List[int] = []
-        plans: Dict[int, Any] = {}
-        admitted: List[Request] = []
-        for b, req in zip(free, group):
-            L = req.tokens.size
-            if self.paged:
-                budget = min(req.max_new_tokens, self.max_len - L)
-                try:
-                    plan = self.kv.admit(b, req.tokens, budget,
-                                         hint=req.prefix_hint)
-                except PoolExhausted as e:
-                    # pool pressure: defer this (and following) requests;
-                    # pages the failed eviction pass DID free still need
-                    # their stale traps disarmed. The dispatch lease (if
-                    # any) stays held for the retry.
-                    self._note_freed(e.freed)
-                    self.stats["admit_deferred"] += 1
-                    self._queue.extendleft(
-                        reversed(group[len(admitted):]))
-                    break
-                if req.prefix_hint is not None:
-                    # admit pinned whatever it mapped; the dispatch-time
-                    # lease has done its job
-                    self._note_freed(self.kv.release(req.prefix_hint[1]))
-                    req.prefix_hint = None
-                plans[b] = plan
-                starts[b] = plan.reuse_len
-                req.reuse_len = plan.reuse_len
-                if plan.reuse_len:
-                    self.stats["prefix_hits"] += 1
-                    self.stats["prefix_hit_tokens"] += plan.reuse_len
-                self.stats["cow_copies"] += len(plan.cow)
-                self._note_freed(plan.freed)
-            admit[b] = True
-            lengths[b] = L
-            taken.append(b)
-            admitted.append(req)
-            self.slots[b] = req
-            self._lengths[b] = L
-            req.prefill_step = self.step_no
-        if not admitted:
-            return
+        with self._phase("serve.admit", "admit_s",
+                         queued=len(self._queue)):
+            free = [b for b, r in enumerate(self.slots) if r is None]
+            group: List[Request] = []
+            while free[len(group):] and self._queue \
+                    and self._queue[0].arrival <= self.step_no:
+                group.append(self._queue.popleft())
+            if self.content_dedup and len(group) > 1:
+                group = self._dedup_group(group)
+            if not group:
+                return
+            B = self.num_slots
+            admit = np.zeros(B, bool)
+            starts = np.zeros(B, np.int32)
+            lengths = np.ones(B, np.int32)
+            taken: List[int] = []
+            plans: Dict[int, Any] = {}
+            admitted: List[Request] = []
+            for b, req in zip(free, group):
+                L = req.tokens.size
+                if self.paged:
+                    budget = min(req.max_new_tokens, self.max_len - L)
+                    try:
+                        plan = self.kv.admit(b, req.tokens, budget,
+                                             hint=req.prefix_hint)
+                    except PoolExhausted as e:
+                        # pool pressure: defer this (and following)
+                        # requests; pages the failed eviction pass DID
+                        # free still need their stale traps disarmed. The
+                        # dispatch lease (if any) stays held for the retry.
+                        self._note_freed(e.freed)
+                        self.stats["admit_deferred"] += 1
+                        self._queue.extendleft(
+                            reversed(group[len(admitted):]))
+                        break
+                    if req.prefix_hint is not None:
+                        # admit pinned whatever it mapped; the dispatch-
+                        # time lease has done its job
+                        self._note_freed(
+                            self.kv.release(req.prefix_hint[1]))
+                        req.prefix_hint = None
+                    plans[b] = plan
+                    starts[b] = plan.reuse_len
+                    req.reuse_len = plan.reuse_len
+                    if plan.reuse_len:
+                        self.stats["prefix_hits"] += 1
+                        self.stats["prefix_hit_tokens"] += plan.reuse_len
+                    self.stats["cow_copies"] += len(plan.cow)
+                    self._note_freed(plan.freed)
+                admit[b] = True
+                lengths[b] = L
+                taken.append(b)
+                admitted.append(req)
+                self.slots[b] = req
+                self._lengths[b] = L
+                req.prefill_step = self.step_no
+            if not admitted:
+                return
 
-        # power-of-two padding of the group's (suffix) lengths for a
-        # bounded jit cache, capped at the cache extent
-        suffixes = [int(lengths[b] - starts[b]) for b in taken]
-        P = min(_bucket(max(suffixes)), self.max_len)
-        toks = np.zeros((B, P), np.int32)
-        for b, req in zip(taken, admitted):
-            suf = req.tokens[int(starts[b]):]
-            toks[b, :suf.size] = suf
-            if self.detectors is not None:
-                # dense: the prefill store sweeps the padded extent [0,P)
-                # of the slot's row; paged: only freshly-owned pages are
-                # written, so there is no stale-row sweep to trap
-                self.detectors.on_admit(
-                    self.step_no, b, req.rid, req.tokens,
-                    padded_len=None if self.paged else P,
-                    reuse_len=int(starts[b]))
-
-        if self.paged:
-            self.cache = self.model.with_page_table(self.cache, self.kv.pt)
-            cows = [c for b in taken for c in plans[b].cow]
-            if cows:
-                # copy-on-write of partially reused pages, padded to the
-                # slot count so one compiled shape serves every group
-                src = np.full(B, 0, np.int32)
-                dst = np.full(B, self.kv.num_pages, np.int32)  # dropped
-                for i, (s, d) in enumerate(cows):
-                    src[i], dst[i] = s, d
-                self.cache = self._copy_fn(self.cache, jnp.asarray(src),
-                                           jnp.asarray(dst))
-            # the copy consumed the COW sources (value semantics: this
-            # cache already holds the copied rows) — drop their pins
-            for b in taken:
-                self._note_freed(self.kv.release(plans[b].cow_pins))
-
-        t0 = time.perf_counter()
-        toks_out, self.cache = self._prefill_fn(
-            self.params, self.cache, jnp.asarray(toks),
-            jnp.asarray(admit), jnp.asarray(starts), jnp.asarray(lengths),
-            self.tokens)
-        toks_out.block_until_ready()
-        self.stats["prefill_s"] += time.perf_counter() - t0
-        self.stats["prefill_tokens"] += int(sum(r.tokens.size
-                                                for r in admitted))
-        self.stats["prefill_computed_tokens"] += int(sum(suffixes))
-        self.stats["padded_prefill_tokens"] += B * P - int(sum(suffixes))
-        self.stats["prefills"] += 1
-        self.tokens = toks_out
-        self._emit_kernel_store("prefill")
-        if self.paged:
+            # power-of-two padding of the group's (suffix) lengths for a
+            # bounded jit cache, capped at the cache extent
+            suffixes = [int(lengths[b] - starts[b]) for b in taken]
+            P = min(_bucket(max(suffixes)), self.max_len)
+            toks = np.zeros((B, P), np.int32)
             for b, req in zip(taken, admitted):
-                self._note_freed(self.kv.register_prefix(b, req.tokens))
-        host = np.asarray(toks_out)[:, 0]
-        for b, req in zip(taken, admitted):
-            self._accept_token(b, req, host[b])
+                suf = req.tokens[int(starts[b]):]
+                toks[b, :suf.size] = suf
+                if self.detectors is not None:
+                    # dense: the prefill store sweeps the padded extent
+                    # [0,P) of the slot's row; paged: only freshly-owned
+                    # pages are written, so there is no stale-row sweep
+                    # to trap
+                    with self._phase("serve.jxperf", "jxperf_s"):
+                        self.detectors.on_admit(
+                            self.step_no, b, req.rid, req.tokens,
+                            padded_len=None if self.paged else P,
+                            reuse_len=int(starts[b]))
+
+            if self.paged:
+                self.cache = self.model.with_page_table(self.cache,
+                                                        self.kv.pt)
+                cows = [c for b in taken for c in plans[b].cow]
+                if cows:
+                    # copy-on-write of partially reused pages, padded to
+                    # the slot count so one compiled shape serves every
+                    # group
+                    src = np.full(B, 0, np.int32)
+                    dst = np.full(B, self.kv.num_pages, np.int32)  # dropped
+                    for i, (s, d) in enumerate(cows):
+                        src[i], dst[i] = s, d
+                    self.cache = self._copy_fn(self.cache, jnp.asarray(src),
+                                               jnp.asarray(dst))
+                # the copy consumed the COW sources (value semantics: this
+                # cache already holds the copied rows) — drop their pins
+                for b in taken:
+                    self._note_freed(self.kv.release(plans[b].cow_pins))
+
+        with self._phase("serve.prefill", "prefill_s",
+                         admitted=len(admitted), bucket=P):
+            toks_out, self.cache = self._prefill_fn(
+                self.params, self.cache, jnp.asarray(toks),
+                jnp.asarray(admit), jnp.asarray(starts),
+                jnp.asarray(lengths), self.tokens)
+            toks_out.block_until_ready()
+        with self._phase("serve.accept", "accept_s"):
+            self.stats["prefill_tokens"] += int(sum(r.tokens.size
+                                                    for r in admitted))
+            self.stats["prefill_computed_tokens"] += int(sum(suffixes))
+            self.stats["padded_prefill_tokens"] += (B * P
+                                                    - int(sum(suffixes)))
+            self.stats["prefills"] += 1
+            self.tokens = toks_out
+            self._emit_kernel_store("prefill")
+            if self.paged:
+                for b, req in zip(taken, admitted):
+                    self._note_freed(self.kv.register_prefix(b, req.tokens))
+            host = np.asarray(toks_out)[:, 0]
+            for b, req in zip(taken, admitted):
+                self._accept_token(b, req, host[b])
 
     def _decode_tick(self) -> None:
         if self.spec:
@@ -547,46 +611,48 @@ class ServeEngine:
             return
         active = np.array([r is not None for r in self.slots])
         write_pos = self._lengths.copy()   # the position each slot writes
-        t0 = time.perf_counter()
-        nxt, self.cache = self._tick_fn(self.params, self.cache,
-                                        self.tokens, jnp.asarray(active))
-        nxt.block_until_ready()
-        self.stats["decode_s"] += time.perf_counter() - t0
-        self.stats["decode_tokens"] += int(active.sum())
-        self.stats["ticks"] += 1
-        self.tokens = nxt
-        self._emit_kernel_store("decode")
-        self._lengths[active] += 1
-        host = np.asarray(nxt)[:, 0]
-        slots_now = list(self.slots)
-        for b, req in enumerate(slots_now):
-            if req is not None:
-                self._accept_token(b, req, host[b])
-        self._report_tick_writes(slots_now, write_pos)
+        live = int(active.sum())
+        with self._phase("serve.decode", "decode_s", live=live):
+            nxt, self.cache = self._tick_fn(self.params, self.cache,
+                                            self.tokens, jnp.asarray(active))
+            nxt.block_until_ready()
+        with self._phase("serve.accept", "accept_s"):
+            self.stats["decode_tokens"] += live
+            self.stats["ticks"] += 1
+            self.tokens = nxt
+            self._emit_kernel_store("decode")
+            self._lengths[active] += 1
+            host = np.asarray(nxt)[:, 0]
+            slots_now = list(self.slots)
+            for b, req in enumerate(slots_now):
+                if req is not None:
+                    self._accept_token(b, req, host[b])
+            self._report_tick_writes(slots_now, write_pos)
 
     def _report_tick_writes(self, slots_now, write_pos) -> None:
         """Tier-3 reporting of one tick's first-position K/V stores."""
         if self.detectors is None:
             return
-        writes = []
-        for b, req in enumerate(slots_now):
-            pos = int(write_pos[b])
-            if self.paged:
-                # idle slots write NOTHING in the paged layout — the
-                # scatter dropped their store, so there is no event;
-                # a slot that just finished freed its pages (site
-                # lookup comes back unmapped) and is skipped too
-                if req is None:
-                    continue
-                page, off = self.kv.site(b, pos)
-                if page < 0:
-                    continue
-            else:
-                page, off = b, pos
-            writes.append(SlotWrite(b, req.rid if req is not None
-                                    else None, req is not None, pos,
-                                    page=page, offset=off))
-        self.detectors.on_step(self.step_no, writes, self._peek)
+        with self._phase("serve.jxperf", "jxperf_s"):
+            writes = []
+            for b, req in enumerate(slots_now):
+                pos = int(write_pos[b])
+                if self.paged:
+                    # idle slots write NOTHING in the paged layout — the
+                    # scatter dropped their store, so there is no event;
+                    # a slot that just finished freed its pages (site
+                    # lookup comes back unmapped) and is skipped too
+                    if req is None:
+                        continue
+                    page, off = self.kv.site(b, pos)
+                    if page < 0:
+                        continue
+                else:
+                    page, off = b, pos
+                writes.append(SlotWrite(b, req.rid if req is not None
+                                        else None, req is not None, pos,
+                                        page=page, offset=off))
+            self.detectors.on_step(self.step_no, writes, self._peek)
 
     # ------------------------- speculative tick -----------------------
     def _draft_cap(self, slot: int, req: Request) -> int:
@@ -620,63 +686,72 @@ class ServeEngine:
         toks = np.zeros((B, W), np.int32)
         toks[:, 0] = np.asarray(self.tokens)[:, 0]
         dlen = np.zeros(B, np.int32)
-        t0 = time.perf_counter()
-        for b, req in enumerate(self.slots):
-            if req is None:
-                continue
-            cap = self._draft_cap(b, req)
-            if cap <= 0:
-                continue
-            hist = np.concatenate(
-                [req.tokens, np.asarray(req.generated, np.int32)])
-            d = np.asarray(self.drafter.propose(hist, cap),
-                           np.int32).reshape(-1)[:cap]
-            dlen[b] = d.size
-            toks[b, 1:1 + d.size] = d
-        self.stats["draft_s"] += time.perf_counter() - t0
+        live = int(active.sum())
+        with self._phase("serve.draft", "draft_s", live=live):
+            for b, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                cap = self._draft_cap(b, req)
+                if cap <= 0:
+                    continue
+                hist = np.concatenate(
+                    [req.tokens, np.asarray(req.generated, np.int32)])
+                d = np.asarray(self.drafter.propose(hist, cap),
+                               np.int32).reshape(-1)[:cap]
+                dlen[b] = d.size
+                toks[b, 1:1 + d.size] = d
 
-        t0 = time.perf_counter()
-        g, m, nxt, self.cache = self._verify_fn(
-            self.params, self.cache, jnp.asarray(toks),
-            jnp.asarray(active), jnp.asarray(dlen))
-        nxt.block_until_ready()
-        dt = time.perf_counter() - t0
-        self.stats["verify_s"] += dt
-        self.stats["decode_s"] += dt
-        self.stats["ticks"] += 1
-        self.stats["spec_ticks"] += 1
-        self.stats["draft_proposed"] += int(dlen[active].sum())
-        self.stats["verified_positions"] += int(active.sum()) * W
-        g = np.asarray(g)
-        m = np.asarray(m)
-        self.stats["draft_accepted"] += int(m[active].sum())
-        self.tokens = nxt
-        counts = self._read_kernel_counts()
-        if counts is not None:
-            # overwrite mode: the verify forward's full-window stores;
-            # rollback: the commit's accepted-prefix stores (the deferred
-            # window stored nothing) — classification against m happens
-            # in the detector, measurement stays in-kernel
-            self.detectors.on_kernel_verify(self.step_no, counts, m, dlen,
-                                            active)
-        self._lengths[active] += 1 + m[active]
+        with self._phase("serve.decode", "decode_s", "verify_s", live=live):
+            g, m, nxt, self.cache = self._verify_fn(
+                self.params, self.cache, jnp.asarray(toks),
+                jnp.asarray(active), jnp.asarray(dlen))
+            nxt.block_until_ready()
+        with self._phase("serve.accept", "accept_s"):
+            self.stats["ticks"] += 1
+            self.stats["spec_ticks"] += 1
+            self.stats["draft_proposed"] += int(dlen[active].sum())
+            self.stats["verified_positions"] += live * W
+            g = np.asarray(g)
+            m = np.asarray(m)
+            self.stats["draft_accepted"] += int(m[active].sum())
+            self.tokens = nxt
+            if self.kernel_counters and self.detectors is not None:
+                with self._phase("serve.jxperf", "jxperf_s"):
+                    counts = self._read_kernel_counts()
+                    if counts is not None:
+                        # overwrite mode: the verify forward's full-window
+                        # stores; rollback: the commit's accepted-prefix
+                        # stores (the deferred window stored nothing) —
+                        # classification against m happens in the
+                        # detector, measurement stays in-kernel
+                        self.detectors.on_kernel_verify(
+                            self.step_no, counts, m, dlen, active)
+            self._lengths[active] += 1 + m[active]
 
-        slots_now = list(self.slots)
-        emitted = 0
-        for b, req in enumerate(slots_now):
-            if req is None:
-                continue
-            # emit the accepted chain + bonus; stop at EOS/limit so the
-            # output stream is exactly the plain-decode stream
-            for j in range(int(m[b]) + 1):
-                emitted += 1
-                self._accept_token(b, req, int(g[b, j]))
-                if req.done:
-                    break
-        self.stats["decode_tokens"] += emitted
+            slots_now = list(self.slots)
+            emitted = 0
+            for b, req in enumerate(slots_now):
+                if req is None:
+                    continue
+                # emit the accepted chain + bonus; stop at EOS/limit so
+                # the output stream is exactly the plain-decode stream
+                for j in range(int(m[b]) + 1):
+                    emitted += 1
+                    self._accept_token(b, req, int(g[b, j]))
+                    if req.done:
+                        break
+            self.stats["decode_tokens"] += emitted
 
-        self._report_tick_writes(slots_now, write_pos)
-        if self.detectors is not None:
+            self._report_tick_writes(slots_now, write_pos)
+            self._report_verify_writes(slots_now, active, write_pos, dlen,
+                                       m)
+
+    def _report_verify_writes(self, slots_now, active, write_pos, dlen,
+                              m) -> None:
+        """Tier-3 reporting of one verify's draft-row stores."""
+        if self.detectors is None:
+            return
+        with self._phase("serve.jxperf", "jxperf_s"):
             entries = []
             for b, req in enumerate(slots_now):
                 if req is None or not active[b]:
@@ -688,8 +763,7 @@ class ServeEngine:
                 # under rollback. Overwrite also stores the fixed-width
                 # window's padding rows past dlen — dead too, but not
                 # the drafter's waste, so they stay out of this site
-                n_written = int(m[b]) if self.spec_rollback \
-                    else int(dlen[b])
+                n_written = int(m[b]) if self.spec_rollback else int(dlen[b])
                 sites = []
                 for j in range(1, n_written + 1):
                     pos = pos0 + j
@@ -708,8 +782,12 @@ class ServeEngine:
     def step(self) -> None:
         """One scheduler step: admit into free slots, then one decode
         tick over the whole batch."""
-        self._admit()
-        self._decode_tick()
+        cpu0 = time.thread_time()
+        with self._phase("serve.step", "step_s", step=self.step_no):
+            self._admit()
+            self._decode_tick()
+        self.stats["step_cpu_s"] += time.thread_time() - cpu0
+        self.stats["steps"] += 1
         self.step_no += 1
 
     def run(self, max_steps: int = 100_000) -> Dict[str, Request]:

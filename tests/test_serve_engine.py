@@ -330,3 +330,29 @@ def test_engine_stats_monotonic_across_generations():
     before = dict(eng.stats)
     eng.stats["new_gauge"] += 1
     assert eng.stats["new_gauge"] == 2.5 and before["new_gauge"] == 1.5
+
+
+@pytest.mark.parametrize("jxperf", [False, True])
+def test_step_phase_counters(jxperf):
+    """Every `step()` counts itself and its phases' host seconds; the
+    seconds spent in calls made for JXPerf (`jxperf_s`) are counted only
+    when its detectors and kernel counters are attached."""
+    cfg, model, params = _model()
+    det = (ServingDetectors(ProfilerConfig(enabled=True, seed=0))
+           if jxperf else None)
+    eng = ServeEngine(model, params, num_slots=2, max_len=32,
+                      kv_layout="paged", page_size=8, detectors=det,
+                      kernel_counters=jxperf)
+    rng = np.random.RandomState(5)
+    for i in range(3):
+        eng.submit(Request(rid=f"r{i}", max_new_tokens=3,
+                           tokens=rng.randint(0, cfg.vocab_size, 6 + i)
+                           .astype(np.int32)))
+    eng.run(max_steps=50)
+    st = eng.stats
+    assert st["steps"] == eng.step_no > 0
+    assert st["step_s"] >= (st["admit_s"] + st["prefill_s"]
+                            + st["decode_s"] + st["accept_s"])
+    assert 0 < st["step_cpu_s"]
+    assert (st["jxperf_s"] > 0) == jxperf
+    assert st["jxperf_s"] <= st["step_s"]

@@ -156,7 +156,8 @@ def serve_phase(cfg, seed):
     from repro.models.zoo import build_model
     from repro.serve.spec import make_drafter
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(seed))
+    # cast once: both engines below hold this one compute-dtype tree
+    params = model.compute_params(model.init(jax.random.PRNGKey(seed)))
     prompts = make_prompts(cfg.vocab_size, seed)
     n = len(prompts)
     max_len = max(p.size for p in prompts) + SERVE_GEN + SPEC_K + 1

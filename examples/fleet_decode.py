@@ -48,7 +48,8 @@ def main():
 
     cfg = registry.get_config(args.arch).smoke()
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    # cast once: every replica and the single engine share this tree
+    params = model.compute_params(model.init(jax.random.PRNGKey(args.seed)))
 
     # staggered arrivals (one request every few ticks): each duplicate
     # lands after the prefix was published, so the policies differ only

@@ -63,6 +63,9 @@ def _build_fleet(model, params, *, replicas, slots, max_len, page_size,
         # keeps hot prefixes resident; tests shrink it deliberately to
         # exercise the pressure/preemption paths.
         num_pages = (slots + 2) * (-(-max_len // page_size))
+    # cast once for every replica: each engine's own cast is then the
+    # identity, and the replicas hold one compute-dtype copy between them
+    params = model.compute_params(params)
     engines, dets = [], []
     for i in range(replicas):
         det = ServingDetectors(ProfilerConfig(enabled=True, seed=seed + i)) \

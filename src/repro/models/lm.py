@@ -148,6 +148,12 @@ def _init_sub_cache(cfg: ModelConfig, typ: str, batch: int, max_len: int,
     raise ValueError(typ)
 
 
+# Parameter leaves the forward reads only through ``.astype(cfg.dtype)``
+# (LM.compute_params): linear ``w``, MoE expert stacks, embedding, head.
+_COMPUTE_LEAVES = frozenset({"w", "w_up", "w_gate", "w_down", "embed",
+                             "head"})
+
+
 def _stack_cache(tree, n: int):
     return jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(x, (n,) + x.shape), tree)
@@ -239,6 +245,27 @@ class LM:
                 main[name] = blk
             out["main"] = main
         return out
+
+    def compute_params(self, params) -> Any:
+        """``params`` with every leaf the forward reads only as
+        ``w.astype(cfg.dtype)`` already in ``cfg.dtype``.
+
+        Those leaves are the projection matrices (``w``), the MoE expert
+        stacks (``w_up``/``w_gate``/``w_down``), the embedding and an
+        untied head. The matmuls then get the same inputs, and the cast
+        is no longer redone inside every jitted decode tick and prefill.
+        Norm scales and the MoE router are read at float32 and stay as
+        given. A leaf already in ``cfg.dtype`` comes back as the same
+        array, so an already-cast tree is returned leaf for leaf.
+        """
+        dt = jnp.dtype(self.cfg.dtype)
+
+        def cast(path, leaf):
+            if (getattr(path[-1], "key", None) in _COMPUTE_LEAVES
+                    and leaf.dtype != dt):
+                return leaf.astype(dt)
+            return leaf
+        return jax.tree_util.tree_map_with_path(cast, params)
 
     # ----------------------------- encoder ---------------------------
     def encode(self, params, frames: jax.Array,

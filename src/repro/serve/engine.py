@@ -175,7 +175,9 @@ class ServeEngine:
         if kv_layout not in KV_LAYOUTS:
             raise ValueError(f"kv_layout must be one of {KV_LAYOUTS}")
         self.model = model
-        self.params = params
+        # weights in the compute dtype once, here, so no jitted step
+        # recasts them (LM.compute_params); the identity on a cast tree
+        self.params = model.compute_params(params)
         self.num_slots = num_slots
         self.max_len = max_len
         self.eos_id = eos_id
@@ -218,7 +220,7 @@ class ServeEngine:
                               prefix_window=prefix_window,
                               registry=registry, owner=f"{owner}/kv")
             cache = model.init_paged_cache(
-                params, num_slots, max_len, page_size=page_size,
+                self.params, num_slots, max_len, page_size=page_size,
                 num_pages=num_pages, kv_dtype=kv_dtype,
                 kernel_counters=self.kernel_counters)
             if registry is not None:
@@ -236,7 +238,7 @@ class ServeEngine:
                              else jax.jit(make_page_copy()))
         else:
             self.kv = None
-            cache = model.init_cache(params, num_slots, max_len,
+            cache = model.init_cache(self.params, num_slots, max_len,
                                      kv_dtype=kv_dtype)
         self.cache = model.with_cache_index(
             cache, jnp.zeros((num_slots,), jnp.int32))
@@ -255,6 +257,8 @@ class ServeEngine:
         self._queue: Deque[Request] = deque()
         self.finished: Dict[str, Request] = {}
         self.step_no = 0
+        given = jax.tree_util.tree_leaves(params)
+        held = jax.tree_util.tree_leaves(self.params)
         self.stats = MonotonicStats(
             {"prefill_tokens": 0, "decode_tokens": 0,
              "prefill_s": 0.0, "decode_s": 0.0, "ticks": 0,
@@ -286,7 +290,14 @@ class ServeEngine:
              # thread's CPU time over step_s: below it, the host waited
              # (on the device, or frozen)
              "steps": 0, "step_s": 0.0, "step_cpu_s": 0.0,
-             "admit_s": 0.0, "accept_s": 0.0, "jxperf_s": 0.0})
+             "admit_s": 0.0, "accept_s": 0.0, "jxperf_s": 0.0,
+             # set at build: the bytes of the given weights replaced by
+             # compute-dtype copies (0 when handed a cast tree), and the
+             # bytes the forward still reads at float32
+             "weights_cast_bytes": sum(a.nbytes for a, b in zip(given, held)
+                                       if a is not b),
+             "weights_f32_bytes": sum(b.nbytes for b in held
+                                      if b.dtype == jnp.float32)})
 
         if step_cache is not None:
             assert step_cache.model is model, \

@@ -146,7 +146,8 @@ def test_scan_profile_merges_and_roundtrips():
 def fleet_env():
     cfg = arch_registry.get_config("qwen3-1.7b").smoke()
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    # cast once, as a fleet does: the replicas share one compute-dtype tree
+    params = model.compute_params(model.init(jax.random.PRNGKey(0)))
     # every burst is a same-tick pair of duplicates; the 36-token shared
     # prefix ends mid-page (page_size 8), the OJXPerf granularity-
     # boundary mismatch the pow2/page candidate ladder alone won't probe

@@ -4,10 +4,14 @@ Nothing runs: each test lowers a kernel at qwen3-1.7b widths (Hq=16,
 Hkv=8, D=128, page 16) against a *described* v5e chip and compiles it
 with the TPU compiler, which refuses what interpret mode accepts (block
 shapes off the (8, 128) tiling, too much VMEM, unpartitionable calls).
+One more compiles the serving engine's whole decode tick, to check what
+the compiler does with the weights it is handed.
 The topology is described inside a fixture, so only the worker that
 runs this file loads the TPU compiler; where it cannot be described the
 tests skip from there.
 """
+import dataclasses
+import re
 from functools import partial
 
 import jax
@@ -15,9 +19,13 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import registry
+from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_prefill import paged_window_attention
 from repro.kernels.paged_attention import paged_decode_attention
+from repro.models.zoo import build_model
+from repro.serve.decode import make_engine_tick
 
 B, HQ, HKV, D, PS, M = 4, 16, 8, 128, 16, 36
 P = B * M
@@ -90,3 +98,35 @@ def test_flash_attention_compiles(sds, grad):
         return jnp.sum(fwd(q, k, v).astype(jnp.float32))
 
     _compile(jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd, q, kv, kv)
+
+
+@pytest.mark.parametrize("cast", [False, True],
+                         ids=["param_dtype", "compute_params"])
+def test_engine_tick_converts_no_weight(sds, monkeypatch, cast):
+    """A 2-layer engine tick at qwen3-1.7b widths (8 slots, paged f32
+    pool, the Pallas decode kernel). Handed float32 weights, the
+    optimised HLO converts whole weight stacks on every tick; handed
+    `LM.compute_params`' tree, no convert yields a weight's shape."""
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    monkeypatch.setattr(ops, "_pallas_interpret", lambda: False)
+    cfg = dataclasses.replace(registry.get_config("qwen3-1.7b"),
+                              num_layers=2)
+    model = build_model(cfg)
+    params = model.abstract_params()
+    if cast:
+        params = jax.eval_shape(model.compute_params, params)
+    cache = jax.eval_shape(lambda: model.init_paged_cache(
+        None, 8, 1024, page_size=PS, num_pages=64, kv_dtype=jnp.float32))
+    place = partial(jax.tree_util.tree_map, lambda a: sds(a.shape, a.dtype))
+    text = _compile(make_engine_tick(model, paged=True), place(params),
+                    place(cache), sds((8, 1), jnp.int32),
+                    sds((8,), jnp.bool_))
+    weights = {tuple(a.shape) for a in jax.tree_util.tree_leaves(params)
+               if a.ndim >= 2}
+    assert (2, 2048, 6144) in weights and (2, 6144, 2048) in weights
+    converted = {tuple(int(d) for d in m.split(",") if d) for m in
+                 re.findall(r"= \w+\[([\d,]*)\]\S*\s+convert\(", text)}
+    if cast:
+        assert not converted & weights, sorted(converted & weights)
+    else:
+        assert {(2, 2048, 6144), (2, 6144, 2048)} <= converted

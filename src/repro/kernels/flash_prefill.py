@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -49,7 +50,7 @@ from repro.kernels.flash_attention import NEG_INF, online_softmax_update
 from repro.kernels.paged_attention import counter_row
 
 
-def _window_kernel(pt_ref, idx_ref, q_ref, kw_ref, vw_ref, wv_ref,
+def _window_kernel(pt_ref, idx_ref, layer_ref, q_ref, kw_ref, vw_ref, wv_ref,
                    k_ref, v_ref,
                    o_ref, lse_ref, cnt_ref,
                    m_scr, l_scr, acc_scr, *,
@@ -146,12 +147,16 @@ def _window_kernel(pt_ref, idx_ref, q_ref, kw_ref, vw_ref, wv_ref,
 def paged_window_attention(q: jax.Array, k_win: jax.Array, v_win: jax.Array,
                            pool_k: jax.Array, pool_v: jax.Array,
                            pt: jax.Array, idx: jax.Array, *,
+                           layer: Optional[jax.Array] = None,
                            store: bool = True,
                            block_q: int = 128,
                            tol: float = 0.0,
                            interpret: bool = False):
     """q: (B, S, Hq, D) at per-slot offsets idx (B,); k_win/v_win:
-    (B, S, Hkv, D); pool: (P, page, Hkv, D); pt: (B, M).
+    (B, S, Hkv, D); pool: (P, page, Hkv, D), or the layer-stacked
+    (L, P, page, Hkv, D) with ``layer`` the layer attended and stored
+    (scalar-prefetched, as in ``paged_decode_attention``; the store
+    lands in place at [layer, page, offset]); pt: (B, M).
 
     Returns ``(out, lse, counters, new_pool_k, new_pool_v)``: out
     (B, S, Hq, D); lse (B, Hq, S); counters (B, 3) int32. With
@@ -163,7 +168,7 @@ def paged_window_attention(q: jax.Array, k_win: jax.Array, v_win: jax.Array,
     (the ref path yields NaN there; the engine discards both).
     """
     B, S, Hq, D = q.shape
-    P, ps, Hkv, _ = pool_k.shape
+    P, ps, Hkv, _ = pool_k.shape[-4:]
     M = pt.shape[1]
     G = Hq // Hkv
     scale = 1.0 / math.sqrt(D)
@@ -171,6 +176,9 @@ def paged_window_attention(q: jax.Array, k_win: jax.Array, v_win: jax.Array,
 
     pt = pt.astype(jnp.int32)
     idx = idx.astype(jnp.int32)
+    stack_k, stack_v = ((pool_k[None], pool_v[None]) if layer is None
+                        else (pool_k, pool_v))
+    lay = jnp.asarray(0 if layer is None else layer, jnp.int32).reshape(1)
 
     # window validity per mode (page-table reads only — O(B*S) scalars)
     gpos = jnp.maximum(idx, 0)[:, None] + jnp.arange(S)[None, :]   # (B, S)
@@ -209,21 +217,23 @@ def paged_window_attention(q: jax.Array, k_win: jax.Array, v_win: jax.Array,
     def wv_index(b, qi, mi, *_):
         return (b, 0, 0)
 
-    def pool_index(b, qi, mi, pt_ref, idx_ref):
+    def pool_index(b, qi, mi, pt_ref, idx_ref, layer_ref):
         w0 = jnp.maximum(idx_ref[b], 0) // ps
         page_i = jnp.where(mi < M, mi, jnp.clip(w0 + mi - M - 1, 0, M - 1))
-        return (jnp.clip(pt_ref[b, page_i], 0, P - 1), 0, 0, 0)
+        return (layer_ref[0], jnp.clip(pt_ref[b, page_i], 0, P - 1),
+                0, 0, 0)
 
+    pool_block = (pl.Squeezed(), 1, ps, Hkv, D)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, Hq, block_q, D), q_index),
             pl.BlockSpec((1, S + 2 * ps, Hkv, D), slot_index),
             pl.BlockSpec((1, S + 2 * ps, Hkv, D), slot_index),
             pl.BlockSpec((1, 1, S), wv_index),
-            pl.BlockSpec((1, ps, Hkv, D), pool_index),
-            pl.BlockSpec((1, ps, Hkv, D), pool_index),
+            pl.BlockSpec(pool_block, pool_index),
+            pl.BlockSpec(pool_block, pool_index),
         ],
         out_specs=[
             pl.BlockSpec((1, Hq, block_q, D), q_index),
@@ -249,10 +259,11 @@ def paged_window_attention(q: jax.Array, k_win: jax.Array, v_win: jax.Array,
             dimension_semantics=("arbitrary",) * 3),
         interpret=interpret,
         name="paged_window_attention",
-    )(pt, idx, qt, kw, vw, wv, pool_k, pool_v)
+    )(pt, idx, lay, qt, kw, vw, wv, stack_k, stack_v)
 
     if store:
-        npk, npv = _ref.paged_update(pool_k, pool_v, k_win, v_win, pt, idx)
+        npk, npv = _ref.paged_update(pool_k, pool_v, k_win, v_win, pt, idx,
+                                     layer=layer)
     else:
         npk, npv = pool_k, pool_v
     out = out[:, :, :S].transpose(0, 2, 1, 3)           # (B, S, Hq, D)

@@ -83,9 +83,14 @@ COUNTER_TOL = 0.0
 
 
 def paged_decode(q, k_new, v_new, pool_k, pool_v, pt, idx, *,
-                 counters: bool = False):
+                 layer=None, counters: bool = False):
     """One-token paged-attention decode: attend slot history + the new
     K/V row, scatter the row through the page table.
+
+    ``pool_k``/``pool_v`` are one layer's (P, page, Hkv, D) pools, or
+    the layer-stacked (L, P, page, Hkv, D) pools with ``layer`` the
+    layer attended: the decode scan carries the stacked pool, and the
+    row lands in place at [layer, page, offset] of it.
 
     Returns ``(out, ck, cv, cnt)`` — cnt is the (B, 3) int32 store-site
     waste counter block ([stored, silent, dropped] elements, see
@@ -100,28 +105,23 @@ def paged_decode(q, k_new, v_new, pool_k, pool_v, pt, idx, *,
     if _use_pallas():
         from repro.kernels.paged_attention import paged_decode_attention
         out, _, cnt = paged_decode_attention(
-            q, k_new, v_new, pool_k, pool_v, pt, idx,
+            q, k_new, v_new, pool_k, pool_v, pt, idx, layer=layer,
             tol=COUNTER_TOL, interpret=_pallas_interpret())
-        ck, cv = _ref.paged_update(pool_k, pool_v, k_new, v_new, pt, idx)
+        ck, cv = _ref.paged_update(pool_k, pool_v, k_new, v_new, pt, idx,
+                                   layer=layer)
         return out, ck, cv, (cnt if counters else None)
-    cnt = None
-    if counters:
-        cnt = _ref.paged_store_counts(pool_k, pool_v, k_new, v_new, pt, idx,
-                                      tol=COUNTER_TOL)
-    dt = q.dtype
-    ck, cv = _ref.paged_update(pool_k, pool_v, k_new, v_new, pt, idx)
-    gk, valid = _ref.paged_gather(ck, pt)
-    gv, _ = _ref.paged_gather(cv, pt)
-    out = _ref.attention_ref(q, gk.astype(dt), gv.astype(dt), causal=True,
-                             q_offset=idx, kv_len=idx + 1, kv_valid=valid)
-    return out, ck, cv, cnt
+    out, ck, cv, cnt = _ref.paged_decode_ref(
+        q, k_new, v_new, pool_k, pool_v, pt, idx, tol=COUNTER_TOL,
+        layer=layer)
+    return out, ck, cv, (cnt if counters else None)
 
 
 def paged_window(q, k_win, v_win, pool_k, pool_v, pt, idx, *,
-                 store: bool = True, counters: bool = False):
+                 layer=None, store: bool = True, counters: bool = False):
     """S-token paged window forward (prefill chunk / width-k verify):
     attend committed history + the in-window causal part, and — store
     mode — write the window rows into the pool through the page table.
+    Pools and ``layer`` as in ``paged_decode``.
 
     Returns ``(out, ck, cv, cnt)`` like ``paged_decode``; with
     ``store=False`` ("defer"/rollback verify) the pool is untouched and
@@ -130,12 +130,12 @@ def paged_window(q, k_win, v_win, pool_k, pool_v, pt, idx, *,
     if _use_pallas():
         from repro.kernels.flash_prefill import paged_window_attention
         out, _, cnt, ck, cv = paged_window_attention(
-            q, k_win, v_win, pool_k, pool_v, pt, idx, store=store,
-            tol=COUNTER_TOL, interpret=_pallas_interpret())
+            q, k_win, v_win, pool_k, pool_v, pt, idx, layer=layer,
+            store=store, tol=COUNTER_TOL, interpret=_pallas_interpret())
         return out, ck, cv, (cnt if counters else None)
     out, ck, cv, cnt = _ref.paged_window_ref(
         q, k_win, v_win, pool_k, pool_v, pt, idx, store=store,
-        tol=COUNTER_TOL)
+        tol=COUNTER_TOL, layer=layer)
     return out, ck, cv, (cnt if counters else None)
 
 

@@ -5,7 +5,10 @@ kernel gathers K/V pages from the pool *inside* the kernel: the page
 table and per-slot positions are scalar-prefetched, and each kv grid
 step's BlockSpec index map chases ``pt[b, m]`` directly, so the
 (B, M*page) logical view the ref path materializes in HBM
-(``ref.paged_gather``) never exists. The new token's K/V row is spliced
+(``ref.paged_gather``) never exists. The pool may be the layer-stacked
+(L, P, page, Hkv, D) array the decode scan carries: the layer is a
+third scalar prefetch and the index map reads [layer, pt[b, m]], so no
+layer's pool is ever sliced out. The new token's K/V row is spliced
 into its page block in VMEM (the pool scatter itself stays a cheap
 O(B*Hkv*D) ``ref.paged_update`` outside the kernel — one row per slot).
 
@@ -36,6 +39,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -53,7 +57,8 @@ def counter_row(stored, silent, dropped):
     return jnp.where(lane == 0, stored, jnp.where(lane == 1, silent, dropped))
 
 
-def _decode_kernel(pt_ref, idx_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
+def _decode_kernel(pt_ref, idx_ref, layer_ref, q_ref, kn_ref, vn_ref,
+                   k_ref, v_ref,
                    o_ref, lse_ref, cnt_ref,
                    m_scr, l_scr, acc_scr, *,
                    scale: float, ps: int, Hkv: int, tol: float):
@@ -119,10 +124,15 @@ def _decode_kernel(pt_ref, idx_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
 def paged_decode_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
                            pool_k: jax.Array, pool_v: jax.Array,
                            pt: jax.Array, idx: jax.Array, *,
+                           layer: Optional[jax.Array] = None,
                            tol: float = 0.0,
                            interpret: bool = False):
-    """q/k_new/v_new: (B, 1, H*, D); pool: (P, page, Hkv, D); pt: (B, M);
-    idx: (B,) per-slot positions (negative = idle slot, attends nothing).
+    """q/k_new/v_new: (B, 1, H*, D); pool: (P, page, Hkv, D), or the
+    layer-stacked (L, P, page, Hkv, D) with ``layer`` the layer attended
+    (scalar-prefetched beside the page table, so each page DMA reads
+    [layer, pt[b, m]] of the stacked pool and no layer is sliced out);
+    pt: (B, M); idx: (B,) per-slot positions (negative = idle slot,
+    attends nothing).
 
     Returns ``(out, lse, counters)``: out (B, 1, Hq, D); lse (B, Hq)
     per-(slot, head) log-sum-exp for sharded flash combines (NEG_INF
@@ -134,13 +144,16 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     """
     B, S, Hq, D = q.shape
     assert S == 1, "decode kernel is single-token"
-    P, ps, Hkv, _ = pool_k.shape
+    if layer is None:                     # one layer's pool: a stack of 1
+        pool_k, pool_v, layer = pool_k[None], pool_v[None], 0
+    _, P, ps, Hkv, _ = pool_k.shape
     M = pt.shape[1]
     G = Hq // Hkv
     scale = 1.0 / math.sqrt(D)
 
     pt = pt.astype(jnp.int32)
     idx = idx.astype(jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     q4 = q.reshape(B, Hkv, G, D)
     # round-trip the new row through the pool dtype: the ref path attends
     # the value the pool actually stores, so the splice must match it bit
@@ -149,24 +162,25 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     kn = k_new.reshape(B, Hkv, D).astype(pdt)
     vn = v_new.reshape(B, Hkv, D).astype(pdt)
 
-    def slot_index(b, m, pt_ref, idx_ref):
+    def slot_index(b, m, *_):
         return (b, 0, 0, 0)
 
-    def new_index(b, m, pt_ref, idx_ref):
+    def new_index(b, m, *_):
         return (b, 0, 0)
 
-    def pool_index(b, m, pt_ref, idx_ref):
-        return (jnp.clip(pt_ref[b, m], 0, P - 1), 0, 0, 0)
+    def pool_index(b, m, pt_ref, idx_ref, layer_ref):
+        return (layer_ref[0], jnp.clip(pt_ref[b, m], 0, P - 1), 0, 0, 0)
 
+    pool_block = (pl.Squeezed(), 1, ps, Hkv, D)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, M),
         in_specs=[
             pl.BlockSpec((1, Hkv, G, D), slot_index),
             pl.BlockSpec((1, Hkv, D), new_index),
             pl.BlockSpec((1, Hkv, D), new_index),
-            pl.BlockSpec((1, ps, Hkv, D), pool_index),
-            pl.BlockSpec((1, ps, Hkv, D), pool_index),
+            pl.BlockSpec(pool_block, pool_index),
+            pl.BlockSpec(pool_block, pool_index),
         ],
         out_specs=[
             pl.BlockSpec((1, Hkv, G, D), slot_index),
@@ -192,5 +206,5 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="paged_decode_attention",
-    )(pt, idx, q4, kn, vn, pool_k, pool_v)
+    )(pt, idx, layer, q4, kn, vn, pool_k, pool_v)
     return out.reshape(B, 1, Hq, D), lse.reshape(B, Hq), cnt.reshape(B, 3)

@@ -6,6 +6,7 @@ well and GSPMD partitions them automatically.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -67,15 +68,40 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # Paged KV cache: page-table scatter (store) and gather (load) between
 # the logical per-slot view and the flat page pool (serve/kv_cache.py).
 # ----------------------------------------------------------------------
+def _layer_base(pool: jax.Array, layer: Optional[jax.Array]):
+    """Flat row index of ``layer``'s first pool row: a pool is
+    (P, page, Hkv, D), or layer-stacked (L, P, page, Hkv, D) with
+    ``layer`` naming the layer addressed (0 for an unstacked pool).
+    Reads and writes of a stacked pool index its flat rows,
+    ``pool.reshape(-1, Hkv, D)``, so no layer is ever sliced out."""
+    if layer is None:
+        return 0
+    return layer * (pool.shape[-4] * pool.shape[-3])
+
+
+def _page_of(pos: jax.Array, pt: jax.Array, ps: int) -> jax.Array:
+    """Pool page of each logical position (-1 where unmapped or
+    outside the table)."""
+    M = pt.shape[1]
+    page_i = jnp.floor_divide(pos, ps)
+    return jnp.where(
+        (page_i >= 0) & (page_i < M),
+        jnp.take_along_axis(pt, jnp.clip(page_i, 0, M - 1), axis=1), -1)
+
+
 def paged_update(pool_k: jax.Array, pool_v: jax.Array, k_new: jax.Array,
                  v_new: jax.Array, pt: jax.Array,
                  idx: jax.Array,
-                 length: Optional[jax.Array] = None) -> tuple:
+                 length: Optional[jax.Array] = None,
+                 layer: Optional[jax.Array] = None) -> tuple:
     """Scatter new K/V rows into a paged pool through the page table.
 
-    pool: (P, page, Hkv, D); k_new/v_new: (B, S, Hkv, D); pt: (B, M)
-    page table (-1 = unmapped); idx: (B,) per-slot write positions. Row
-    (b, s) lands at logical position idx[b]+s -> page pt[b, pos//page].
+    pool: (P, page, Hkv, D), or (L, P, page, Hkv, D) with ``layer`` the
+    layer written (the decode scan's loop-carried pool: the rows land
+    in place at [layer, page, offset]); k_new/v_new: (B, S, Hkv, D);
+    pt: (B, M) page table (-1 = unmapped); idx: (B,) per-slot write
+    positions. Row (b, s) lands at logical position idx[b]+s -> page
+    pt[b, pos//page].
     Stores whose position is negative (engine idle-slot sentinel) or
     whose page is unmapped are DROPPED — idle/finished slots write
     nothing past their page-table extent, which is exactly the dead/
@@ -86,23 +112,20 @@ def paged_update(pool_k: jax.Array, pool_v: jax.Array, k_new: jax.Array,
     accepted prefix of a verify window this way (LM.commit_verify), so
     rejected draft rows never reach the pool at all.
     """
-    P, ps = pool_k.shape[0], pool_k.shape[1]
-    B, S = k_new.shape[0], k_new.shape[1]
-    M = pt.shape[1]
+    ps = pool_k.shape[-3]
+    S = k_new.shape[1]
     pos = idx[:, None] + jnp.arange(S)[None, :]            # (B,S) logical
     if length is not None:
         pos = jnp.where(jnp.arange(S)[None, :] < length[:, None], pos, -1)
-    page_i = jnp.floor_divide(pos, ps)
-    page = jnp.where(
-        (page_i >= 0) & (page_i < M),
-        jnp.take_along_axis(pt, jnp.clip(page_i, 0, M - 1), axis=1), -1)
+    page = _page_of(pos, pt, ps)
     flat = jnp.where((page >= 0) & (pos >= 0),
-                     page * ps + jnp.remainder(pos, ps), P * ps)
+                     _layer_base(pool_k, layer) + page * ps
+                     + jnp.remainder(pos, ps), math.prod(pool_k.shape[:-2]))
 
     def scat(pool, new):
-        fp = pool.reshape((P * ps,) + pool.shape[2:])
-        fp = fp.at[flat].set(new.astype(pool.dtype), mode="drop")
-        return fp.reshape(pool.shape)
+        rows = pool.reshape((-1,) + pool.shape[-2:])
+        rows = rows.at[flat].set(new.astype(pool.dtype), mode="drop")
+        return rows.reshape(pool.shape)
     return scat(pool_k, k_new), scat(pool_v, v_new)
 
 
@@ -110,9 +133,11 @@ def paged_store_counts(pool_k: jax.Array, pool_v: jax.Array,
                        k_new: jax.Array, v_new: jax.Array,
                        pt: jax.Array, idx: jax.Array,
                        length: Optional[jax.Array] = None,
-                       tol: float = 0.0) -> jax.Array:
+                       tol: float = 0.0,
+                       layer: Optional[jax.Array] = None) -> jax.Array:
     """Waste counters for a ``paged_update`` store, per slot: (B, 3) int32
-    ``[stored, silent, dropped]`` element counts over K and V.
+    ``[stored, silent, dropped]`` element counts over K and V (pools and
+    ``layer`` as in ``paged_update``).
 
     This is the pure-jnp oracle for the in-kernel store-site counters
     (kernel tier, see DESIGN.md): *stored* elements land through the
@@ -123,23 +148,20 @@ def paged_store_counts(pool_k: jax.Array, pool_v: jax.Array,
     slots (negative positions) attempt no store and count nothing.
     """
     from repro.core.events import silent_mask
-    P, ps = pool_k.shape[0], pool_k.shape[1]
+    ps = pool_k.shape[-3]
     B, S, Hkv, D = k_new.shape
-    M = pt.shape[1]
     pos = idx[:, None] + jnp.arange(S)[None, :]            # (B, S)
     attempted = pos >= 0
     if length is not None:
         attempted = attempted & (jnp.arange(S)[None, :] < length[:, None])
-    page_i = jnp.floor_divide(pos, ps)
-    page = jnp.where(
-        (page_i >= 0) & (page_i < M),
-        jnp.take_along_axis(pt, jnp.clip(page_i, 0, M - 1), axis=1), -1)
+    page = _page_of(pos, pt, ps)
     landing = attempted & (page >= 0)
 
-    flat = jnp.where(landing, page * ps + jnp.remainder(pos, ps), 0)
+    flat = jnp.where(landing, _layer_base(pool_k, layer) + page * ps
+                     + jnp.remainder(pos, ps), 0)
 
     def row_silent(pool, new):
-        old = pool.reshape((P * ps,) + pool.shape[2:])[flat]   # (B,S,Hkv,D)
+        old = pool.reshape((-1,) + pool.shape[-2:])[flat]   # (B,S,Hkv,D)
         oldf = old.astype(jnp.float32)
         newf = new.astype(pool.dtype).astype(jnp.float32)
         return jnp.sum(silent_mask(oldf, newf, tol), axis=(2, 3),
@@ -158,15 +180,18 @@ def paged_store_counts(pool_k: jax.Array, pool_v: jax.Array,
 def paged_decode_ref(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
                      pool_k: jax.Array, pool_v: jax.Array,
                      pt: jax.Array, idx: jax.Array,
-                     tol: float = 0.0) -> tuple:
+                     tol: float = 0.0,
+                     layer: Optional[jax.Array] = None) -> tuple:
     """Oracle for the paged-attention decode kernel: the store-then-
     gather-then-mask composition the serving fallback path runs, plus
-    the store-site waste counters. Returns (out, ck, cv, counters)."""
+    the store-site waste counters (pools and ``layer`` as in
+    ``paged_update``). Returns (out, ck, cv, counters)."""
     dt = q.dtype
-    cnt = paged_store_counts(pool_k, pool_v, k_new, v_new, pt, idx, tol=tol)
-    ck, cv = paged_update(pool_k, pool_v, k_new, v_new, pt, idx)
-    gk, valid = paged_gather(ck, pt)
-    gv, _ = paged_gather(cv, pt)
+    cnt = paged_store_counts(pool_k, pool_v, k_new, v_new, pt, idx, tol=tol,
+                             layer=layer)
+    ck, cv = paged_update(pool_k, pool_v, k_new, v_new, pt, idx, layer=layer)
+    gk, valid = paged_gather(ck, pt, layer)
+    gv, _ = paged_gather(cv, pt, layer)
     out = attention_ref(q, gk.astype(dt), gv.astype(dt), causal=True,
                         q_offset=idx, kv_len=idx + 1, kv_valid=valid)
     return out, ck, cv, cnt
@@ -175,28 +200,31 @@ def paged_decode_ref(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
 def paged_window_ref(q: jax.Array, k_win: jax.Array, v_win: jax.Array,
                      pool_k: jax.Array, pool_v: jax.Array,
                      pt: jax.Array, idx: jax.Array, *,
-                     store: bool = True, tol: float = 0.0) -> tuple:
+                     store: bool = True, tol: float = 0.0,
+                     layer: Optional[jax.Array] = None) -> tuple:
     """Oracle for the fused paged window kernel (prefill / verify).
 
     ``store=True`` is the scatter-then-gather composition the overwrite
     paths run (all S window rows stored through the page table, then
     attention over the gathered view); ``store=False`` is the "defer"
     composition (window spliced into the gathered view, pool untouched,
-    zero store counters). Returns (out, ck, cv, counters).
+    zero store counters). Pools and ``layer`` as in ``paged_update``.
+    Returns (out, ck, cv, counters).
     """
     dt = q.dtype
     B, S = q.shape[:2]
     if store:
         cnt = paged_store_counts(pool_k, pool_v, k_win, v_win, pt, idx,
-                                 tol=tol)
-        ck, cv = paged_update(pool_k, pool_v, k_win, v_win, pt, idx)
-        gk, valid = paged_gather(ck, pt)
-        gv, _ = paged_gather(cv, pt)
+                                 tol=tol, layer=layer)
+        ck, cv = paged_update(pool_k, pool_v, k_win, v_win, pt, idx,
+                              layer=layer)
+        gk, valid = paged_gather(ck, pt, layer)
+        gv, _ = paged_gather(cv, pt, layer)
     else:
         cnt = jnp.zeros((B, 3), jnp.int32)
         ck, cv = pool_k, pool_v
-        gk, valid = paged_gather(pool_k, pt)
-        gv, _ = paged_gather(pool_v, pt)
+        gk, valid = paged_gather(pool_k, pt, layer)
+        gv, _ = paged_gather(pool_v, pt, layer)
         ext = gk.shape[1]
         pos = idx[:, None] + jnp.arange(S)[None, :]
         tgt = jnp.where((pos >= 0) & (pos < ext), pos, ext)
@@ -209,15 +237,19 @@ def paged_window_ref(q: jax.Array, k_win: jax.Array, v_win: jax.Array,
     return out, ck, cv, cnt
 
 
-def paged_gather(pool: jax.Array, pt: jax.Array) -> tuple:
-    """Logical per-slot view of a paged pool: (B, M*page, ...) plus the
-    (B, M*page) validity mask (False where the page table is unmapped —
-    gathered garbage there must be masked, see attention_ref.kv_valid).
+def paged_gather(pool: jax.Array, pt: jax.Array,
+                 layer: Optional[jax.Array] = None) -> tuple:
+    """Logical per-slot view of a paged pool (or of ``layer`` of a
+    stacked one): (B, M*page, ...) plus the (B, M*page) validity mask
+    (False where the page table is unmapped — gathered garbage there
+    must be masked, see attention_ref.kv_valid).
     """
-    P, ps = pool.shape[0], pool.shape[1]
+    P, ps = pool.shape[-4], pool.shape[-3]
     B, M = pt.shape
-    g = jnp.take(pool, jnp.clip(pt, 0, P - 1), axis=0)     # (B,M,page,...)
-    g = g.reshape((B, M * ps) + pool.shape[2:])
+    pages = pool.reshape((-1,) + pool.shape[-3:])        # (L*P, page, ...)
+    first = 0 if layer is None else layer * P
+    g = jnp.take(pages, first + jnp.clip(pt, 0, P - 1), axis=0)
+    g = g.reshape((B, M * ps) + pool.shape[-2:])
     valid = jnp.repeat(pt >= 0, ps, axis=1)
     return g, valid
 

@@ -158,7 +158,7 @@ def apply_attention(p, cfg: ModelConfig, x: jax.Array, *,
         counters = "kcnt" in cache
         out, _, _, cnt = ops.paged_window(
             q, k, v, cache["k"], cache["v"], pt, idx,
-            store=False, counters=counters)
+            layer=cache.get("layer"), store=False, counters=counters)
         new_cache = {**cache, "idx": idx + S, "win_k": k, "win_v": v}
         if counters:
             new_cache["kcnt"] = cnt     # all-zero: no stores in defer mode
@@ -170,10 +170,14 @@ def apply_attention(p, cfg: ModelConfig, x: jax.Array, *,
         # page table (B,M), per-slot positions (B,). Stores scatter
         # through the table (out-of-table/idle writes DROP — no dead
         # rewrites); reads gather the logical view back, masked where
-        # the table is unmapped.
+        # the table is unmapped. With a "layer" entry the pools are the
+        # decode scan's layer-stacked (L,P,page,Hkv,D) carry, read and
+        # written in place at that layer.
         from repro.kernels import ops
         idx = cache["idx"]
         pt = cache["pt"]
+        layer = cache.get("layer")
+        pool_k, pool_v = cache["k"], cache["v"]
         counters = "kcnt" in cache
 
         def _finish(out, ck, cv, cnt):
@@ -181,50 +185,56 @@ def apply_attention(p, cfg: ModelConfig, x: jax.Array, *,
             if counters:
                 if cnt is None:       # sharded paths count host-side
                     cnt = ops.paged_store_counts(
-                        cache["k"], cache["v"], k, v, pt, idx,
-                        tol=ops.COUNTER_TOL)
+                        pool_k, pool_v, k, v, pt, idx,
+                        tol=ops.COUNTER_TOL, layer=layer)
                 new_cache["kcnt"] = cnt
             out = out.reshape(B, S, H * D)
             out = out @ p["wo"]["w"].astype(dt)
             return shard(out, "btd"), new_cache
 
+        def _sharded(entry):
+            # a page-chunk-sharded pool: each shard scatters the rows
+            # it owns and the per-query partials combine flash-style;
+            # the shard_map takes one layer's pool, so a stacked pool's
+            # layer is cut out and written back
+            from repro.serve.flash_decode import paged_shard_plan
+            from repro.sharding.ctx import current_sharder
+            sharder = current_sharder()
+            plan = paged_shard_plan(sharder, B, pool_k.shape[-4],
+                                    pool_k.shape[-3])
+            if plan is None:
+                return None
+            b_ax, s_ax = plan
+            lk, lv = ((pool_k, pool_v) if layer is None
+                      else (pool_k[layer], pool_v[layer]))
+            out, ck, cv = entry(q, k, v, lk, lv, pt, idx, mesh=sharder.mesh,
+                                batch_axes=b_ax, seq_axes=s_ax)
+            if layer is not None:
+                ck, cv = pool_k.at[layer].set(ck), pool_v.at[layer].set(cv)
+            return _finish(out, ck, cv, None)
+
         if S == 1:
             from repro.serve.flash_decode import (
-                decode_paged_attention_sharded, paged_shard_plan)
-            from repro.sharding.ctx import current_sharder
-            sharder = current_sharder()
-            plan = paged_shard_plan(sharder, B, cache["k"].shape[0],
-                                    cache["k"].shape[1])
-            if plan is not None:
-                b_ax, s_ax = plan
-                out, ck, cv = decode_paged_attention_sharded(
-                    q, k, v, cache["k"], cache["v"], pt, idx,
-                    mesh=sharder.mesh, batch_axes=b_ax, seq_axes=s_ax)
-                return _finish(out, ck, cv, None)
+                decode_paged_attention_sharded)
+            done = _sharded(decode_paged_attention_sharded)
+            if done is not None:
+                return done
             out, ck, cv, cnt = ops.paged_decode(
-                q, k, v, cache["k"], cache["v"], pt, idx, counters=counters)
+                q, k, v, pool_k, pool_v, pt, idx, layer=layer,
+                counters=counters)
             return _finish(out, ck, cv, cnt)
         if spec == "overwrite":
-            # width-k speculative verify against a page-chunk-sharded
-            # pool: each shard scatters the window rows it owns and the
-            # per-query partials combine flash-style
+            # width-k speculative verify against a sharded pool
             from repro.serve.flash_decode import (
-                paged_shard_plan, verify_paged_attention_sharded)
-            from repro.sharding.ctx import current_sharder
-            sharder = current_sharder()
-            plan = paged_shard_plan(sharder, B, cache["k"].shape[0],
-                                    cache["k"].shape[1])
-            if plan is not None:
-                b_ax, s_ax = plan
-                out, ck, cv = verify_paged_attention_sharded(
-                    q, k, v, cache["k"], cache["v"], pt, idx,
-                    mesh=sharder.mesh, batch_axes=b_ax, seq_axes=s_ax)
-                return _finish(out, ck, cv, None)
+                verify_paged_attention_sharded)
+            done = _sharded(verify_paged_attention_sharded)
+            if done is not None:
+                return done
         # prefill chunk / verify-overwrite window: fused window forward
         # (Pallas kernel with in-kernel page gather + paged-write
         # epilogue, or the scatter-then-gather ref composition)
         out, ck, cv, cnt = ops.paged_window(
-            q, k, v, cache["k"], cache["v"], pt, idx,
+            q, k, v, pool_k, pool_v, pt, idx, layer=layer,
             store=True, counters=counters)
         return _finish(out, ck, cv, cnt)
     elif cache is not None and kv_src is None:

@@ -754,8 +754,8 @@ class LM:
                 x, nc = superblock(x, (p_l, cache["main"][li]))
                 new_main.append(nc)
         else:
-            x, new_main = jax.lax.scan(superblock, x,
-                                       (params["main"], cache["main"]))
+            x, new_main = self._scan_layers(superblock, x, params["main"],
+                                            cache["main"])
         new_cache = {"main": new_main}
         if sch.tail:
             def tailblk(x, inp):
@@ -769,6 +769,43 @@ class LM:
         w = self.head_weight(params)
         logits = jnp.einsum("bsd,vd->bsv", x, w.astype(dt))
         return _mask_pad_vocab(logits, cfg), new_cache
+
+    @staticmethod
+    def _scan_layers(superblock, x, p_main, c_main):
+        """Scan ``superblock`` over the stacked layers.
+
+        A sub-cache with a page table (a paged KV pool) sends its
+        stacked ``k``/``v`` pools through the loop carry with the layer
+        counter, and each layer's attention reads and writes them in
+        place at that layer (the sub-cache it sees holds the whole
+        pools and ``"layer"``). Every other leaf — and every other
+        sub-cache — rides xs/ys, sliced per layer. Carrying the pools
+        keeps one pool buffer through the loop: as xs/ys each layer
+        would slice its pool out, update a copy and write it back."""
+        pooled = [n for n, sub in c_main.items() if "pt" in sub]
+        pools = {n: {"k": c_main[n]["k"], "v": c_main[n]["v"]}
+                 for n in pooled}
+        xs = {n: ({k: a for k, a in sub.items() if k not in ("k", "v")}
+                  if n in pools else sub) for n, sub in c_main.items()}
+
+        def body(carry, inp):
+            x, layer, pools = carry
+            p_layer, c_layer = inp
+            c_layer = {n: ({**c, **pools[n], "layer": layer}
+                           if n in pools else c)
+                       for n, c in c_layer.items()}
+            x, new_c = superblock(x, (p_layer, c_layer))
+            pools = {n: {"k": new_c[n]["k"], "v": new_c[n]["v"]}
+                     for n in pools}
+            ys = {n: ({k: a for k, a in c.items()
+                       if k not in ("k", "v", "layer")}
+                      if n in pools else c) for n, c in new_c.items()}
+            return (x, layer + 1, pools), ys
+
+        (x, _, pools), ys = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.int32), pools), (p_main, xs))
+        return x, {n: ({**c, **pools[n]} if n in pools else c)
+                   for n, c in ys.items()}
 
     def _cached_xattn(self, p_attn, x, c):
         """Cross-attention against precomputed cached KV."""

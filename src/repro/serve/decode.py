@@ -32,7 +32,10 @@ class StepCache:
     (kind, paged, rollback) combination compiles once and every replica
     dispatches through the same executable. Also what makes routing-
     policy A/B timing honest: both fleets run literally the same
-    compiled code."""
+    compiled code. A `ServeEngine` handed none builds its own.
+
+    Every program donates its cache argument: after a call the cache
+    passed in is deleted, and only the returned one may be used."""
 
     def __init__(self, model, strategy=None):
         self.model = model
@@ -45,20 +48,24 @@ class StepCache:
         fn = self._fns.get(key)
         if fn is None:
             if kind == "tick":
-                fn = jax.jit(make_engine_tick(self.model, self.strategy,
-                                              paged=paged))
+                step = make_engine_tick(self.model, self.strategy,
+                                        paged=paged)
             elif kind == "prefill":
-                fn = jax.jit(make_engine_prefill(self.model, self.strategy,
-                                                 paged=paged))
+                step = make_engine_prefill(self.model, self.strategy,
+                                           paged=paged)
             elif kind == "verify":
-                fn = jax.jit(make_engine_verify(self.model, self.strategy,
-                                                paged=paged,
-                                                rollback=rollback))
+                step = make_engine_verify(self.model, self.strategy,
+                                          paged=paged, rollback=rollback)
             elif kind == "page_copy":
                 from repro.serve.kv_cache import make_page_copy
-                fn = jax.jit(make_page_copy())
+                step = make_page_copy()
             else:
                 raise ValueError(f"unknown step kind {kind!r}")
+            # every program donates the cache it is handed (never the
+            # params): the KV pool is updated in place and handed back
+            # aliased, so one pool is live, not two
+            fn = jax.jit(step, donate_argnums=(0,) if kind == "page_copy"
+                         else (1,))
             self._fns[key] = fn
         return fn
 

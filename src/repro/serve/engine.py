@@ -70,10 +70,8 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.core.detectors import ServingDetectors, SlotWrite, VerifyWrite
-from repro.serve.decode import (make_engine_prefill, make_engine_tick,
-                                make_engine_verify)
-from repro.serve.kv_cache import (PagedKV, PoolExhausted, _digest,
-                                  make_page_copy)
+from repro.serve.decode import StepCache
+from repro.serve.kv_cache import PagedKV, PoolExhausted, _digest
 
 ENGINE_FAMILIES = ("dense", "moe")
 KV_LAYOUTS = ("dense", "paged")
@@ -233,9 +231,6 @@ class ServeEngine:
                     for sub in cache["main"].values() if "pt" in sub
                     for key in ("k", "v"))
                 a.page_reader = self._read_page
-            self._copy_fn = (step_cache.get("page_copy")
-                             if step_cache is not None
-                             else jax.jit(make_page_copy()))
         else:
             self.kv = None
             cache = model.init_cache(self.params, num_slots, max_len,
@@ -299,22 +294,20 @@ class ServeEngine:
              "weights_f32_bytes": sum(b.nbytes for b in held
                                       if b.dtype == jnp.float32)})
 
+        # the jitted programs; each donates the cache (StepCache), so
+        # self.cache is the only live reference to the pool
         if step_cache is not None:
             assert step_cache.model is model, \
                 "step_cache was built for a different model"
-            self._tick_fn = step_cache.get("tick", paged=self.paged)
-            self._prefill_fn = step_cache.get("prefill", paged=self.paged)
-            self._verify_fn = step_cache.get(
-                "verify", paged=self.paged,
-                rollback=self.spec_rollback) if self.spec else None
         else:
-            self._tick_fn = jax.jit(
-                make_engine_tick(model, strategy, paged=self.paged))
-            self._prefill_fn = jax.jit(
-                make_engine_prefill(model, strategy, paged=self.paged))
-            self._verify_fn = jax.jit(make_engine_verify(
-                model, strategy, paged=self.paged,
-                rollback=self.spec_rollback)) if self.spec else None
+            step_cache = StepCache(model, strategy)
+        self._tick_fn = step_cache.get("tick", paged=self.paged)
+        self._prefill_fn = step_cache.get("prefill", paged=self.paged)
+        self._verify_fn = step_cache.get(
+            "verify", paged=self.paged,
+            rollback=self.spec_rollback) if self.spec else None
+        if self.paged:
+            self._copy_fn = step_cache.get("page_copy")
 
         # detector geometry: the KV sub-blocks of one scanned superblock
         main = self.cache["main"]

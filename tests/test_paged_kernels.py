@@ -14,6 +14,7 @@ import dataclasses
 import os
 import subprocess
 import sys
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -229,6 +230,117 @@ def test_ops_dispatch_parity(monkeypatch):
 
 
 # ---------------------------------------------------------------------
+# layer-stacked pools: the decode scan carries (L, P, page, Hkv, D) and
+# each layer reads and writes its own slab in place
+# ---------------------------------------------------------------------
+
+NL = 3
+
+
+def _stacked_pools():
+    ks = jax.random.split(jax.random.PRNGKey(31), 2)
+    sk = jax.random.normal(ks[0], (NL, P, PS, HKV, D), jnp.float32)
+    sv = jax.random.normal(ks[1], (NL, P, PS, HKV, D), jnp.float32)
+    return sk, sv
+
+
+def _assert_stacked_equal(layer, sk, sv, got, want):
+    """``got`` from a stacked call at ``layer``, ``want`` from the same
+    call on that layer's pools: equal outputs, and the stacked pools
+    returned equal the inputs with ``want``'s pools at ``layer``."""
+    *g_rest, g_k, g_v = got
+    *w_rest, w_k, w_v = want
+    for g, w in zip(g_rest, w_rest):
+        if w is None:
+            assert g is None
+        else:
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    np.testing.assert_array_equal(np.asarray(g_k),
+                                  np.asarray(sk.at[layer].set(w_k)))
+    np.testing.assert_array_equal(np.asarray(g_v),
+                                  np.asarray(sv.at[layer].set(w_v)))
+
+
+@pytest.mark.parametrize("layer", [0, NL - 1])
+def test_decode_kernel_stacked_pool_equals_layer_pool(layer):
+    sk, sv = _stacked_pools()
+    q, kn, vn = _rows(1)
+    pt, idx = jnp.asarray(HOSTILE_PT), jnp.asarray(HOSTILE_IDX)
+    got = paged_decode_attention(q, kn, vn, sk, sv, pt, idx,
+                                 layer=jnp.int32(layer), interpret=True)
+    want = paged_decode_attention(q, kn, vn, sk[layer], sv[layer], pt, idx,
+                                  interpret=True)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("store", [True, False])
+@pytest.mark.parametrize("layer", [0, NL - 1])
+def test_window_kernel_stacked_pool_equals_layer_pool(layer, store):
+    sk, sv = _stacked_pools()
+    q, kw, vw = _rows(3, seed=7)
+    pt, idx = jnp.asarray(HOSTILE_PT), jnp.asarray(HOSTILE_IDX_W)
+    got = paged_window_attention(q, kw, vw, sk, sv, pt, idx,
+                                 layer=jnp.int32(layer), store=store,
+                                 interpret=True)
+    want = paged_window_attention(q, kw, vw, sk[layer], sv[layer], pt, idx,
+                                  store=store, interpret=True)
+    _assert_stacked_equal(layer, sk, sv, got, want)
+
+
+@pytest.mark.parametrize("counters", [False, True])
+@pytest.mark.parametrize("pallas", [False, True], ids=["ref", "pallas"])
+@pytest.mark.parametrize("layer", [0, NL - 1])
+@pytest.mark.parametrize("call,S,store", [("decode", 1, True),
+                                          ("window", 3, True),
+                                          ("window", 3, False)],
+                         ids=["decode", "window-store", "window-defer"])
+def test_ops_stacked_pool_equals_layer_pool(monkeypatch, call, S, store,
+                                            layer, pallas, counters):
+    """ops.paged_decode / ops.paged_window on the stacked pools at
+    ``layer`` equal the same call on that layer's pools, on both
+    dispatch targets, with the store-site counters on and off."""
+    monkeypatch.setattr(kops, "_use_pallas", lambda: pallas)
+    sk, sv = _stacked_pools()
+    q, kn, vn = _rows(S, seed=19)
+    pt = jnp.asarray(HOSTILE_PT)
+    idx = jnp.asarray(HOSTILE_IDX if S == 1 else HOSTILE_IDX_W)
+    if call == "decode":
+        fn = partial(kops.paged_decode, counters=counters)
+    else:
+        fn = partial(kops.paged_window, store=store, counters=counters)
+    out, ck, cv, cnt = fn(q, kn, vn, sk, sv, pt, idx,
+                          layer=jnp.int32(layer))
+    w_out, w_k, w_v, w_cnt = fn(q, kn, vn, sk[layer], sv[layer], pt, idx)
+    live = np.asarray(idx) >= 0
+    np.testing.assert_array_equal(np.asarray(out)[live],
+                                  np.asarray(w_out)[live])
+    _assert_stacked_equal(layer, sk, sv, (cnt, ck, cv), (w_cnt, w_k, w_v))
+
+
+def test_ref_stacked_store_counts_and_gather():
+    """The ref store counters and gather on a stacked pool read only
+    ``layer``'s slab."""
+    sk, sv = _stacked_pools()
+    _, kn, vn = _rows(1)
+    pt, idx = jnp.asarray(HOSTILE_PT), jnp.asarray(HOSTILE_IDX)
+    for layer in (0, NL - 1):
+        # the pools already hold the row at layer: every store silent
+        hk, hv = kref.paged_update(sk, sv, kn, vn, pt, idx,
+                                   layer=jnp.int32(layer))
+        cnt = kref.paged_store_counts(hk, hv, kn, vn, pt, idx,
+                                      layer=jnp.int32(layer))
+        want = kref.paged_store_counts(hk[layer], hv[layer], kn, vn, pt,
+                                       idx)
+        np.testing.assert_array_equal(np.asarray(cnt), np.asarray(want))
+        assert (np.asarray(cnt)[:2, 1] == 2 * HKV * D).all()
+        g, valid = kref.paged_gather(hk, pt, jnp.int32(layer))
+        g1, valid1 = kref.paged_gather(hk[layer], pt)
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(g1))
+        np.testing.assert_array_equal(np.asarray(valid), np.asarray(valid1))
+
+
+# ---------------------------------------------------------------------
 # model-level: the kcnt leaf rides the decode scan and reports exact
 # element counts at every serving site
 # ---------------------------------------------------------------------
@@ -424,6 +536,42 @@ for dtype in (jnp.float32, jnp.bfloat16):
         np.testing.assert_array_equal(np.asarray(cv_r), np.asarray(cv_p))
         np.testing.assert_allclose(np.asarray(o_r[:2], np.float32),
                                    np.asarray(o_p[:2], np.float32),
+                                   rtol=2e-5, atol=2e-5)
+
+# model level: the decode scan's stacked pool through the sharded paths
+# (each layer is cut out for the shard_map and written back) equals the
+# unsharded stacked-pool path
+import dataclasses
+from repro.configs import registry
+from repro.models.zoo import build_model
+from repro.sharding.ctx import Sharder, sharding_ctx
+
+ops._use_pallas = lambda: False
+cfg = dataclasses.replace(registry.get_config("qwen3-1.7b").smoke(),
+                          dtype="float32")
+model = build_model(cfg)
+params = model.init(jax.random.PRNGKey(0))
+mesh2 = Mesh(np.array(jax.devices()).reshape(1, 2), ("data", "model"))
+sharder = Sharder(mesh2, {}, batch_axes=("data",))
+cache = model.init_paged_cache(params, 2, 64, page_size=4, num_pages=256,
+                               kv_dtype=jnp.float32)
+cache = model.with_page_table(cache, jnp.array(
+    [[3, 200, 7, 130] + [-1] * 12, [250, 1, -1, -1] + [-1] * 12], jnp.int32))
+cache = model.with_cache_index(cache, jnp.array([9, 5], jnp.int32))
+for width, spec in ((1, None), (3, "overwrite")):
+    toks = jax.random.randint(ks[0], (2, width), 0, cfg.vocab_size)
+    step = jax.jit(lambda c, t: model.decode_step(params, c, t, spec=spec))
+    want, w_cache = step(cache, toks)
+    with mesh2, sharding_ctx(sharder):
+        step = jax.jit(lambda c, t: model.decode_step(params, c, t,
+                                                      spec=spec))
+        got, g_cache = step(cache, toks)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    # later layers' rows carry the flash combine's round-off
+    for a, b in zip(jax.tree_util.tree_leaves(g_cache),
+                    jax.tree_util.tree_leaves(w_cache)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-5, atol=2e-5)
 print("SUBPROC_OK")
 """

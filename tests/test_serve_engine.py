@@ -356,3 +356,38 @@ def test_step_phase_counters(jxperf):
     assert 0 < st["step_cpu_s"]
     assert (st["jxperf_s"] > 0) == jxperf
     assert st["jxperf_s"] <= st["step_s"]
+
+
+@pytest.mark.parametrize("mode", ["admit", "decode", "spec", "jxperf"])
+def test_engine_step_donates_cache(mode):
+    """Every engine program donates the cache it is handed: after a
+    `step()` the pool leaves of the cache the step started from are
+    deleted (one pool is live, not two), and the engine serves on from
+    the cache each call hands back — with the detectors' reads of
+    `self.cache` after the calls (``jxperf``) and the speculative
+    verify (``spec``) too."""
+    from repro.serve.spec import NGramDrafter
+    cfg, model, params = _model()
+    det = (ServingDetectors(ProfilerConfig(enabled=True, seed=0))
+           if mode == "jxperf" else None)
+    eng = ServeEngine(model, params, num_slots=2, max_len=32,
+                      kv_layout="paged", page_size=8, detectors=det,
+                      kernel_counters=mode == "jxperf",
+                      drafter=NGramDrafter() if mode == "spec" else None)
+    rng = np.random.RandomState(7)
+    for i in range(2):
+        eng.submit(Request(rid=f"r{i}", max_new_tokens=6,
+                           tokens=rng.randint(0, cfg.vocab_size, 9)
+                           .astype(np.int32)))
+    if mode != "admit":
+        eng.step()                       # admit both: the rest decodes
+    before = eng.cache
+    eng.step()
+    assert eng.stats["prefills"] == 1    # one admission, before or here
+    for sub in before["main"].values():
+        assert sub["k"].is_deleted() and sub["v"].is_deleted()
+    for sub in eng.cache["main"].values():
+        assert not sub["k"].is_deleted()
+    fin = eng.run(max_steps=50)
+    assert sorted(fin) == ["r0", "r1"]
+    assert all(len(r.generated) == 6 for r in fin.values())

@@ -4,8 +4,9 @@ Nothing runs: each test lowers a kernel at qwen3-1.7b widths (Hq=16,
 Hkv=8, D=128, page 16) against a *described* v5e chip and compiles it
 with the TPU compiler, which refuses what interpret mode accepts (block
 shapes off the (8, 128) tiling, too much VMEM, unpartitionable calls).
-One more compiles the serving engine's whole decode tick, to check what
-the compiler does with the weights it is handed.
+Others compile the serving engine's whole decode tick and prefill, to
+check what the compiler does with the weights it is handed and with the
+KV pool it is donated.
 The topology is described inside a fixture, so only the worker that
 runs this file loads the TPU compiler; where it cannot be described the
 tests skip from there.
@@ -25,7 +26,7 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_prefill import paged_window_attention
 from repro.kernels.paged_attention import paged_decode_attention
 from repro.models.zoo import build_model
-from repro.serve.decode import make_engine_tick
+from repro.serve.decode import StepCache, make_engine_tick
 
 B, HQ, HKV, D, PS, M = 4, 16, 8, 128, 16, 36
 P = B * M
@@ -130,3 +131,126 @@ def test_engine_tick_converts_no_weight(sds, monkeypatch, cast):
         assert not converted & weights, sorted(converted & weights)
     else:
         assert {(2, 2048, 6144), (2, 6144, 2048)} <= converted
+
+
+# ---------------------------------------------------------------------
+# the KV pool through the engine's donating programs
+# ---------------------------------------------------------------------
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]"
+                    r"(\{[^}]*\})? ([\w-]+)\((.*)$")
+# memory-space assignment's moves between HBM and VMEM: a pool small
+# enough to fit VMEM may be staged there around its update
+_STAGING = ("copy-start", "copy-done", "slice-start", "slice-done")
+
+
+def _computations(text):
+    """{name: [instruction lines]} of an optimised HLO module's text."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def _pool_values(text, shapes):
+    """(name, opcode, detail) of each instruction outside a fusion's body
+    that yields an array of one of ``shapes``; a fusion's detail is the
+    opcode of its body's root."""
+    comps = _computations(text)
+    fused = set(re.findall(r"calls=%([\w.-]+)", text))
+    root = {n: next(re.search(r"[\]})] ([a-z][\w-]*)\(", ln).group(1)
+                    for ln in body if ln.lstrip().startswith("ROOT "))
+            for n, body in comps.items() if n in fused}
+    out = []
+    for name, body in comps.items():
+        if name in fused:
+            continue
+        for line in body:
+            m = _INSTR.match(line)
+            if not m:
+                continue
+            dims = tuple(int(d) for d in m.group(3).split(",") if d)
+            if dims not in shapes:
+                continue
+            op, rest = m.group(5), m.group(6)
+            if op == "fusion":
+                detail = root[re.search(r"calls=%([\w.-]+)", rest).group(1)]
+            elif op == "custom-call":
+                detail = re.search(r'custom_call_target="([^"]+)"',
+                                   rest).group(1)
+            else:
+                detail = ""
+            out.append((m.group(1), op, detail))
+    return out
+
+
+def _aliased_params(text):
+    return {int(p) for p in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)",
+        text.split("\n", 1)[0])}
+
+
+def _entry_params(text, dims):
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    shape = ",".join(map(str, dims))
+    return {int(p) for p in re.findall(
+        r"= f32\[" + shape + r"\]\S* parameter\((\d+)\)", entry)}
+
+
+@pytest.mark.parametrize("program", ["tick", "prefill"])
+@pytest.mark.parametrize("layers,pages,fits_vmem",
+                         [(2, 64, True), (28, 512, False)],
+                         ids=["2x64", "28x512"])
+def test_engine_programs_update_pool_in_place(sds, monkeypatch, program,
+                                              layers, pages, fits_vmem):
+    """The engine's own donating tick and 64-row prefill (qwen3-1.7b
+    widths, 8 slots, paged f32 pool), at 2 layers over 64 pages and at
+    the deployment's 28 layers over 512. The pool leaves are aliased
+    input to output, and no op outside the in-place scatter yields a
+    value shaped like the whole pool or one layer's pool: no copy, no
+    per-layer slice, no write-back of a slice. The 2-layer pool (8 MB)
+    fits VMEM, where the compiler may stage it around the scatter; the
+    deployment's (1.88 GB) cannot be, and there nothing is excused."""
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    monkeypatch.setattr(ops, "_pallas_interpret", lambda: False)
+    cfg = dataclasses.replace(registry.get_config("qwen3-1.7b"),
+                              num_layers=layers)
+    model = build_model(cfg)
+    params = jax.eval_shape(model.compute_params, model.abstract_params())
+    cache = jax.eval_shape(lambda: model.with_cache_index(
+        model.init_paged_cache(None, 8, 1024, page_size=PS,
+                               num_pages=pages, kv_dtype=jnp.float32),
+        jnp.zeros((8,), jnp.int32)))
+    place = partial(jax.tree_util.tree_map, lambda a: sds(a.shape, a.dtype))
+    i32 = lambda *s: sds(s, jnp.int32)   # noqa: E731
+    on = sds((8,), jnp.bool_)
+    rest = {"tick": (i32(8, 1), on),
+            "prefill": (i32(8, 64), on, i32(8), i32(8), i32(8, 1))}[program]
+    fn = StepCache(model).get(program, paged=True)
+    text = fn.lower(place(params), place(cache), *rest).compile().as_text()
+    assert "tpu_custom_call" in text
+
+    pool = (layers, pages, PS, HKV, D)
+    rows = (layers * pages * PS, HKV, D)
+    shapes = {pool, pool[1:], (1,) + pool[1:], rows,
+              (pages * PS, HKV, D)}
+    pool_params = _entry_params(text, pool)
+    assert len(pool_params) == 2                      # K and V
+    assert pool_params <= _aliased_params(text), text.split("\n", 1)[0]
+    values = _pool_values(text, shapes)
+    assert [v for v in values if v[1] == "fusion"
+            and v[2] == "scatter"], "no in-place scatter of the pool"
+    bad = [v for v in values
+           if v[1] not in ("parameter", "get-tuple-element", "bitcast",
+                           "while")
+           and not (v[1] == "fusion" and v[2] == "scatter")
+           and not (fits_vmem and (v[1] in _STAGING
+                                   or v[2] == "ConcatBitcast"))]
+    assert not bad, bad

@@ -1,10 +1,15 @@
-"""jit'd wrappers + backend dispatch for the Pallas kernels.
+"""jit'd wrappers + backend dispatch for the Pallas kernels: the one
+module that picks which attention implementation runs.
 
-The platform alone picks the path: on a TPU the Pallas kernels run
-compiled; elsewhere the pure-jnp oracles from ``ref.py`` run — they are
-the same math and XLA/GSPMD handles fusion + partitioning. Tests steer
-the dispatch themselves (monkeypatching ``_use_pallas``) to run the
-kernels in interpret mode on CPU against the oracles.
+Two observable facts pick the path. The active sharder first: when its
+mesh admits a shard plan for the cache (``sharded.paged_shard_plan`` /
+``decode_shard_plan``), the flash-decoding entries of ``sharded.py``
+run. Then the platform: on a TPU the Pallas kernels run compiled;
+elsewhere the pure-jnp oracles from ``ref.py`` run — they are the same
+math and XLA/GSPMD handles fusion + partitioning. Tests steer the
+dispatch themselves (monkeypatching ``_use_pallas``, which the sharded
+bodies read too) to run the kernels in interpret mode on CPU against
+the oracles.
 """
 from __future__ import annotations
 
@@ -18,8 +23,8 @@ from jax.sharding import PartitionSpec as PS
 
 from repro.kernels import ref as _ref
 from repro.kernels import flash_attention as _fa
-from repro.sharding.ctx import current_sharder
-from repro.train.fused_xent import shard_map
+from repro.kernels import sharded as _sh
+from repro.sharding.ctx import current_sharder, shard, shard_map
 
 
 def _use_pallas() -> bool:
@@ -70,6 +75,50 @@ def attention(q, k, v, *, causal: bool = True, q_offset=0,
                               kv_len=kv_len, kv_valid=kv_valid)
 
 
+def cached_decode(q, k_new, v_new, cache_k, cache_v, idx):
+    """Decode against a dense (B, Smax, Hkv, D) cache at one scalar
+    write position ``idx`` for the whole batch: write the S new K/V rows
+    at ``idx`` and attend over [0, idx + S). Returns ``(out, ck, cv)``.
+
+    One token against a sequence-chunk-sharded cache runs the sharded
+    flash-decoding entry; anything else updates the cache in place and
+    runs ``attention`` over it."""
+    S = q.shape[1]
+    sharder = current_sharder()
+    plan = (_sh.decode_shard_plan(sharder, k_new.shape[0],
+                                  cache_k.shape[1]) if S == 1 else None)
+    if plan is not None:
+        b_ax, s_ax = plan
+        return _sh.decode_attention_sharded(
+            q, k_new, v_new, cache_k, cache_v, idx, mesh=sharder.mesh,
+            batch_axes=b_ax, seq_axes=s_ax)
+    ck = jax.lax.dynamic_update_slice_in_dim(
+        cache_k, k_new.astype(cache_k.dtype), idx, axis=1)
+    cv = jax.lax.dynamic_update_slice_in_dim(
+        cache_v, v_new.astype(cache_v.dtype), idx, axis=1)
+    k, v = ck.astype(q.dtype), cv.astype(q.dtype)
+    kv_len = idx + S
+    out = attention(shard(q, "bshd"), shard(k, "bskv"), shard(v, "bskv"),
+                    causal=True, q_offset=idx, kv_len=kv_len)
+    return out, ck, cv
+
+
+def cross_attention(q, k, v, *, kv_valid: Optional[jax.Array] = None):
+    """Non-causal attention against precomputed K/V (image tokens,
+    encoder output). A sequence-sharded K/V without a length mask runs
+    the sharded combine; otherwise keys past ``kv_valid`` never
+    contribute."""
+    sharder = current_sharder()
+    plan = (_sh.decode_shard_plan(sharder, q.shape[0], k.shape[1])
+            if kv_valid is None else None)
+    if plan is not None:
+        b_ax, s_ax = plan
+        return _sh.cross_attention_sharded(q, k, v, mesh=sharder.mesh,
+                                           batch_axes=b_ax, seq_axes=s_ax)
+    return attention(q, k.astype(q.dtype), v.astype(q.dtype), causal=False,
+                     kv_valid=kv_valid)
+
+
 # paged-KV scatter/gather: pure-jnp (XLA scatter/gather fuse well and
 # GSPMD partitions them); re-exported here so model code dispatches
 # through one kernel namespace
@@ -80,6 +129,32 @@ paged_store_counts = _ref.paged_store_counts
 # store-site waste-counter tolerance (kernel tier): exact equality, the
 # paper's Def.-2 silent-store semantics for same-dtype overwrites
 COUNTER_TOL = 0.0
+
+
+def _sharded_paged(entry, q, k_new, v_new, pool_k, pool_v, pt, idx, *,
+                   layer, counters):
+    """Run a sharded paged entry when the active sharder has a plan for
+    the pool, else return None. Each shard scatters the rows it owns and
+    the per-query partials combine flash-style; the shard_map takes one
+    layer's pool, so a stacked pool's layer is cut out and written back.
+    The shard bodies' kernel counters are ignored, so the stores are
+    counted here, outside the shard_map."""
+    sharder = current_sharder()
+    plan = _sh.paged_shard_plan(sharder, q.shape[0], pool_k.shape[-4],
+                                pool_k.shape[-3])
+    if plan is None:
+        return None
+    b_ax, s_ax = plan
+    lk, lv = ((pool_k, pool_v) if layer is None
+              else (pool_k[layer], pool_v[layer]))
+    out, ck, cv = entry(q, k_new, v_new, lk, lv, pt, idx, mesh=sharder.mesh,
+                        batch_axes=b_ax, seq_axes=s_ax)
+    if layer is not None:
+        ck, cv = pool_k.at[layer].set(ck), pool_v.at[layer].set(cv)
+    cnt = (paged_store_counts(pool_k, pool_v, k_new, v_new, pt, idx,
+                              tol=COUNTER_TOL, layer=layer)
+           if counters else None)
+    return out, ck, cv, cnt
 
 
 def paged_decode(q, k_new, v_new, pool_k, pool_v, pt, idx, *,
@@ -100,8 +175,14 @@ def paged_decode(q, k_new, v_new, pool_k, pool_v, pt, idx, *,
     scalar-prefetched page table (no logical-view materialization) and
     measures the counters at the store site; only the O(B*Hkv*D)
     single-row scatter runs outside. Ref path: the scatter-gather-mask
-    composition from ``ref.py``.
+    composition from ``ref.py``. A page-chunk-sharded pool runs
+    ``sharded.decode_paged_attention_sharded``.
     """
+    done = _sharded_paged(_sh.decode_paged_attention_sharded, q, k_new,
+                          v_new, pool_k, pool_v, pt, idx, layer=layer,
+                          counters=counters)
+    if done is not None:
+        return done
     if _use_pallas():
         from repro.kernels.paged_attention import paged_decode_attention
         out, _, cnt = paged_decode_attention(
@@ -117,7 +198,8 @@ def paged_decode(q, k_new, v_new, pool_k, pool_v, pt, idx, *,
 
 
 def paged_window(q, k_win, v_win, pool_k, pool_v, pt, idx, *,
-                 layer=None, store: bool = True, counters: bool = False):
+                 layer=None, store: bool = True, verify: bool = False,
+                 counters: bool = False):
     """S-token paged window forward (prefill chunk / width-k verify):
     attend committed history + the in-window causal part, and — store
     mode — write the window rows into the pool through the page table.
@@ -125,8 +207,16 @@ def paged_window(q, k_win, v_win, pool_k, pool_v, pt, idx, *,
 
     Returns ``(out, ck, cv, cnt)`` like ``paged_decode``; with
     ``store=False`` ("defer"/rollback verify) the pool is untouched and
-    cnt is all-zero (no machine-level stores happen).
+    cnt is all-zero (no machine-level stores happen). ``verify`` marks a
+    stored width-k verify window, which against a page-chunk-sharded
+    pool runs ``sharded.verify_paged_attention_sharded``.
     """
+    if store and verify:
+        done = _sharded_paged(_sh.verify_paged_attention_sharded, q, k_win,
+                              v_win, pool_k, pool_v, pt, idx, layer=layer,
+                              counters=counters)
+        if done is not None:
+            return done
     if _use_pallas():
         from repro.kernels.flash_prefill import paged_window_attention
         out, _, cnt, ck, cv = paged_window_attention(

@@ -11,7 +11,10 @@ scale — 56 GB/device/token). The manual plan is textbook flash-decoding:
     max/denominator, and partials combine with one tiny pmax+psum.
 
 Works for any head count, any batch, any cache length (incl. 500k), and
-is exact (same math as ref.attention_ref).
+is exact (same math as ref.attention_ref). `kernels/ops.py` is the only
+caller: it asks the plans below for a layout under the active sharder
+and runs these entries when there is one. Inside the paged shard bodies
+the platform picks Pallas or the jnp composition, through ``ops``.
 """
 from __future__ import annotations
 
@@ -22,7 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as PS
 
-from repro.train.fused_xent import shard_map
+from repro.kernels import ops
+from repro.sharding.ctx import shard_map
 
 
 def _axis_index(names: Tuple[str, ...], mesh) -> jax.Array:
@@ -30,12 +34,6 @@ def _axis_index(names: Tuple[str, ...], mesh) -> jax.Array:
     for n in names:
         idx = idx * mesh.shape[n] + jax.lax.axis_index(n)
     return idx
-
-
-def _pallas_mode() -> Tuple[bool, bool]:
-    """(use Pallas kernels inside the shard bodies, interpret mode)."""
-    from repro.kernels import ops
-    return ops._use_pallas(), ops._pallas_interpret()
 
 
 def _lse_combine(o_l, lse, seq_axes, out_dtype):
@@ -129,7 +127,7 @@ def decode_paged_attention_sharded(q, k_new, v_new, ck, cv, pt, idx, *,
     n_seq = int(np.prod([mesh.shape[a] for a in seq_axes]))
     chunk = P // n_seq                 # pages per shard
     scale = 1.0 / np.sqrt(D)
-    use_pallas, interp = _pallas_mode()
+    use_pallas, interp = ops._use_pallas(), ops._pallas_interpret()
 
     b = batch_axes if batch_axes else None
     q_spec = PS(b, None, None, None)
@@ -170,7 +168,7 @@ def decode_paged_attention_sharded(q, k_new, v_new, ck, cv, pt, idx, *,
             # logical-view gather never materializes; partials combine
             # with the kernel's per-(slot, head) lse. Counters are
             # polluted by non-owner shards and ignored — the engine's
-            # sharded path counts stores host-side (layers._finish).
+            # sharded path counts stores host-side (ops._sharded_paged).
             from repro.kernels.paged_attention import paged_decode_attention
             o_l, lse, _ = paged_decode_attention(
                 q_l, kn, vn, ck_n, cv_n, jnp.where(owned, lpt, -1), idx_l,
@@ -235,7 +233,7 @@ def verify_paged_attention_sharded(q, k_new, v_new, ck, cv, pt, idx, *,
     n_seq = int(np.prod([mesh.shape[a] for a in seq_axes]))
     chunk = P // n_seq                 # pages per shard
     scale = 1.0 / np.sqrt(D)
-    use_pallas, interp = _pallas_mode()
+    use_pallas, interp = ops._use_pallas(), ops._pallas_interpret()
 
     b = batch_axes if batch_axes else None
     q_spec = PS(b, None, None, None)
@@ -258,7 +256,7 @@ def verify_paged_attention_sharded(q, k_new, v_new, ck, cv, pt, idx, *,
             # its committed-history sweep covers the owned pages, and
             # the per-(slot, head, query) lse drives the cross-shard
             # combine. Counters are ignored here — the engine's sharded
-            # path counts stores host-side (layers._finish).
+            # path counts stores host-side (ops._sharded_paged).
             from repro.kernels.flash_prefill import paged_window_attention
             lpt = pt_l - off
             owned = (pt_l >= 0) & (lpt >= 0) & (lpt < chunk)

@@ -60,7 +60,7 @@ def _named(mesh, spec_tree):
 
 
 def run_cell(arch: str, shape_name: str, multi_pod: bool, strategy_name: str,
-             remat: str = "full", decode_unroll: bool = False) -> dict:
+             remat: str = "full") -> dict:
     cfg = registry.get_config(arch)
     shape = SHAPES_BY_NAME[shape_name]
     ok, why = registry.cell_applicable(cfg, shape)
@@ -122,9 +122,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, strategy_name: str,
         wg = (params_bytes / mesh.shape["model"]) > _SERVE_WG_BYTES
         strat = make_strategy("tp_serve", mesh, weight_gathered=wg)
         rec["weight_gathered"] = bool(wg)
-        if decode_unroll:
-            model.decode_unroll = True
-            rec["decode_unroll"] = True
         sstep = make_serve_step(model, strat)
         params_abs, cache_abs, tok_abs = SP.decode_inputs(model, cfg, shape)
         p_specs = strat.param_specs(model)
@@ -173,7 +170,6 @@ def main() -> None:
     ap.add_argument("--strategy", default="dp_tp",
                     help="train/prefill strategy (decode always tp_serve)")
     ap.add_argument("--remat", default="full")
-    ap.add_argument("--decode-unroll", action="store_true")
     ap.add_argument("--tag", default="baseline")
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args()
@@ -198,8 +194,7 @@ def main() -> None:
                       f"{'2x16x16' if mp else '16x16'} ({args.strategy})",
                       flush=True)
                 try:
-                    rec = run_cell(arch, shape, mp, args.strategy, args.remat,
-                                   args.decode_unroll)
+                    rec = run_cell(arch, shape, mp, args.strategy, args.remat)
                 except Exception as e:  # record failures — they are bugs
                     rec = {"arch": arch, "shape": shape,
                            "mesh": "2x16x16" if mp else "16x16",

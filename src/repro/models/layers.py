@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops
 from repro.models import params as P
 from repro.sharding.ctx import shard
 
@@ -47,25 +48,8 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 
 # ----------------------------------------------------------------------
-# Attention (GQA; ref path — the Pallas flash kernel is dispatched in
-# repro.kernels.ops for TPU deployments)
+# Attention (GQA; `repro.kernels.ops` picks the implementation)
 # ----------------------------------------------------------------------
-def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-              causal: bool, q_offset=0,
-              kv_len: Optional[jax.Array] = None,
-              kv_valid: Optional[jax.Array] = None) -> jax.Array:
-    """q: (B,Sq,Hq,D), k/v: (B,Skv,Hkv,D) -> (B,Sq,Hq,D).
-
-    GQA via head grouping; scores accumulated in f32. ``q_offset`` is the
-    absolute position of q[0] (for decode); ``kv_len`` masks cache slots
-    >= kv_len (decode with preallocated cache); ``kv_valid`` masks
-    unmapped page-table positions of a paged cache's gathered view.
-    """
-    from repro.kernels import ops
-    return ops.attention(q, k, v, causal=causal, q_offset=q_offset,
-                         kv_len=kv_len, kv_valid=kv_valid)
-
-
 def decl_attention(cfg: ModelConfig, cross: bool = False) -> Dict[str, Any]:
     d = cfg.d_model
     decl = {
@@ -124,7 +108,6 @@ def apply_attention(p, cfg: ModelConfig, x: jax.Array, *,
         q = apply_rmsnorm(p["q_norm"], q, cfg.norm_eps)
         k = apply_rmsnorm(p["k_norm"], k, cfg.norm_eps)
 
-    q_offset = 0
     if use_rope and kv_src is None:
         if cache is not None:
             idx0 = cache["idx"]
@@ -139,33 +122,12 @@ def apply_attention(p, cfg: ModelConfig, x: jax.Array, *,
             q = apply_rope(q, pos, cfg.rope_theta)
             k = apply_rope(k, pos, cfg.rope_theta)
 
-    new_cache = None
-    kv_len = None
-    if (cache is not None and kv_src is None and "pt" in cache
-            and spec == "defer"):
-        # speculative verify, rollback mode: the pool is NOT written.
-        # Attention runs over the committed history with the verify
-        # window spliced in at its positions — pure activation memory —
-        # and the window K/V ride in win_k/win_v for LM.commit_verify to
-        # scatter only the accepted prefix. Values round-trip through
-        # the pool dtype exactly like the scatter-then-gather path, so
-        # the logits are bit-identical to overwrite mode. Dispatches to
-        # the fused paged window kernel (store disabled) on Pallas
-        # backends, the spliced-gather ref composition elsewhere.
-        from repro.kernels import ops
-        idx = cache["idx"]
-        pt = cache["pt"]
-        counters = "kcnt" in cache
-        out, _, _, cnt = ops.paged_window(
-            q, k, v, cache["k"], cache["v"], pt, idx,
-            layer=cache.get("layer"), store=False, counters=counters)
-        new_cache = {**cache, "idx": idx + S, "win_k": k, "win_v": v}
-        if counters:
-            new_cache["kcnt"] = cnt     # all-zero: no stores in defer mode
-        out = out.reshape(B, S, H * D)
-        out = out @ p["wo"]["w"].astype(dt)
-        return shard(out, "btd"), new_cache
-    elif cache is not None and kv_src is None and "pt" in cache:
+    if cache is None or kv_src is not None:
+        out = ops.attention(shard(q, "bshd"), shard(k, "bskv"),
+                            shard(v, "bskv"), causal=causal and kv_src is None,
+                            kv_valid=kv_valid)
+        new_cache = None
+    elif "pt" in cache:
         # block-paged cache (serve/kv_cache.py): pool (P,page,Hkv,D),
         # page table (B,M), per-slot positions (B,). Stores scatter
         # through the table (out-of-table/idle writes DROP — no dead
@@ -173,93 +135,43 @@ def apply_attention(p, cfg: ModelConfig, x: jax.Array, *,
         # the table is unmapped. With a "layer" entry the pools are the
         # decode scan's layer-stacked (L,P,page,Hkv,D) carry, read and
         # written in place at that layer.
-        from repro.kernels import ops
-        idx = cache["idx"]
-        pt = cache["pt"]
-        layer = cache.get("layer")
-        pool_k, pool_v = cache["k"], cache["v"]
+        idx, pt = cache["idx"], cache["pt"]
         counters = "kcnt" in cache
-
-        def _finish(out, ck, cv, cnt):
+        kw = dict(layer=cache.get("layer"), counters=counters)
+        if spec == "defer":
+            # speculative verify, rollback mode: the pool is NOT written.
+            # Attention runs over the committed history with the verify
+            # window spliced in at its positions — pure activation
+            # memory — and the window K/V ride in win_k/win_v for
+            # LM.commit_verify to scatter only the accepted prefix.
+            # Values round-trip through the pool dtype exactly like the
+            # scatter-then-gather path, so the logits are bit-identical
+            # to overwrite mode; cnt is all-zero (no stores).
+            out, _, _, cnt = ops.paged_window(
+                q, k, v, cache["k"], cache["v"], pt, idx, store=False, **kw)
+            new_cache = {**cache, "idx": idx + S, "win_k": k, "win_v": v}
+        else:
+            if S == 1:
+                out, ck, cv, cnt = ops.paged_decode(
+                    q, k, v, cache["k"], cache["v"], pt, idx, **kw)
+            else:
+                # prefill chunk / verify-overwrite window
+                out, ck, cv, cnt = ops.paged_window(
+                    q, k, v, cache["k"], cache["v"], pt, idx, store=True,
+                    verify=spec == "overwrite", **kw)
             new_cache = {**cache, "k": ck, "v": cv, "idx": idx + S}
-            if counters:
-                if cnt is None:       # sharded paths count host-side
-                    cnt = ops.paged_store_counts(
-                        pool_k, pool_v, k, v, pt, idx,
-                        tol=ops.COUNTER_TOL, layer=layer)
-                new_cache["kcnt"] = cnt
-            out = out.reshape(B, S, H * D)
-            out = out @ p["wo"]["w"].astype(dt)
-            return shard(out, "btd"), new_cache
-
-        def _sharded(entry):
-            # a page-chunk-sharded pool: each shard scatters the rows
-            # it owns and the per-query partials combine flash-style;
-            # the shard_map takes one layer's pool, so a stacked pool's
-            # layer is cut out and written back
-            from repro.serve.flash_decode import paged_shard_plan
-            from repro.sharding.ctx import current_sharder
-            sharder = current_sharder()
-            plan = paged_shard_plan(sharder, B, pool_k.shape[-4],
-                                    pool_k.shape[-3])
-            if plan is None:
-                return None
-            b_ax, s_ax = plan
-            lk, lv = ((pool_k, pool_v) if layer is None
-                      else (pool_k[layer], pool_v[layer]))
-            out, ck, cv = entry(q, k, v, lk, lv, pt, idx, mesh=sharder.mesh,
-                                batch_axes=b_ax, seq_axes=s_ax)
-            if layer is not None:
-                ck, cv = pool_k.at[layer].set(ck), pool_v.at[layer].set(cv)
-            return _finish(out, ck, cv, None)
-
-        if S == 1:
-            from repro.serve.flash_decode import (
-                decode_paged_attention_sharded)
-            done = _sharded(decode_paged_attention_sharded)
-            if done is not None:
-                return done
-            out, ck, cv, cnt = ops.paged_decode(
-                q, k, v, pool_k, pool_v, pt, idx, layer=layer,
-                counters=counters)
-            return _finish(out, ck, cv, cnt)
-        if spec == "overwrite":
-            # width-k speculative verify against a sharded pool
-            from repro.serve.flash_decode import (
-                verify_paged_attention_sharded)
-            done = _sharded(verify_paged_attention_sharded)
-            if done is not None:
-                return done
-        # prefill chunk / verify-overwrite window: fused window forward
-        # (Pallas kernel with in-kernel page gather + paged-write
-        # epilogue, or the scatter-then-gather ref composition)
-        out, ck, cv, cnt = ops.paged_window(
-            q, k, v, pool_k, pool_v, pt, idx, layer=layer,
-            store=True, counters=counters)
-        return _finish(out, ck, cv, cnt)
-    elif cache is not None and kv_src is None:
+        if counters:
+            new_cache["kcnt"] = cnt
+    elif jnp.ndim(cache["idx"]) == 0:
+        # one write position for the whole batch
         idx = cache["idx"]
-        if S == 1 and jnp.ndim(idx) == 0:
-            # one-token decode: sharded flash-decoding when the cache is
-            # sequence-chunk sharded (see serve/flash_decode.py)
-            from repro.serve.flash_decode import (decode_attention_sharded,
-                                                  decode_shard_plan)
-            from repro.sharding.ctx import current_sharder
-            sharder = current_sharder()
-            plan = decode_shard_plan(sharder, Bk if kv_src is None else B,
-                                     cache["k"].shape[1])
-            if plan is not None:
-                b_ax, s_ax = plan
-                out, ck, cv = decode_attention_sharded(
-                    q, k, v, cache["k"], cache["v"], idx,
-                    mesh=sharder.mesh, batch_axes=b_ax, seq_axes=s_ax)
-                new_cache = {"k": ck, "v": cv, "idx": idx + S}
-                out = out.reshape(B, S, H * D)
-                out = out @ p["wo"]["w"].astype(dt)
-                return shard(out, "btd"), new_cache
-        # fallback: in-place update + masked attention (single device /
-        # unshardable shapes)
-        if jnp.ndim(idx) == 1 and spec is not None:
+        out, ck, cv = ops.cached_decode(q, k, v, cache["k"], cache["v"], idx)
+        new_cache = {"k": ck, "v": cv, "idx": idx + S}
+    else:
+        # per-slot write positions (serving engine): each row lands at
+        # its own sequence offset
+        idx = cache["idx"]
+        if spec is not None:
             # width-k verify over dense per-slot rows: a bounded scatter
             # instead of the DUS below — DUS clamps an overflowing start
             # index, which would shift the window back onto committed
@@ -274,28 +186,18 @@ def apply_attention(p, cfg: ModelConfig, x: jax.Array, *,
                 k.astype(cache["k"].dtype), mode="drop")
             cv = cache["v"].at[bidx, tgt].set(
                 v.astype(cache["v"].dtype), mode="drop")
-        elif jnp.ndim(idx) == 1:
-            # per-slot write positions (serving engine): each row lands at
-            # its own sequence offset
+        else:
             upd = jax.vmap(
                 lambda c, u, i: jax.lax.dynamic_update_slice_in_dim(
                     c, u, i, axis=0))
             ck = upd(cache["k"], k.astype(cache["k"].dtype), idx)
             cv = upd(cache["v"], v.astype(cache["v"].dtype), idx)
-        else:
-            ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k.astype(cache["k"].dtype), idx, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v.astype(cache["v"].dtype), idx, axis=1)
         new_cache = {"k": ck, "v": cv, "idx": idx + S}
         k, v = ck.astype(dt), cv.astype(dt)
         kv_len = idx + S
-        q_offset = idx
-        causal = True
-
-    q = shard(q, "bshd")
-    k = shard(k, "bskv")
-    v = shard(v, "bskv")
-    out = attention(q, k, v, causal=causal and kv_src is None,
-                    q_offset=q_offset, kv_len=kv_len, kv_valid=kv_valid)
+        out = ops.attention(shard(q, "bshd"), shard(k, "bskv"),
+                            shard(v, "bskv"), causal=True, q_offset=idx,
+                            kv_len=kv_len, kv_valid=kv_valid)
     out = out.reshape(B, S, H * D)
     out = out @ p["wo"]["w"].astype(dt)
     return shard(out, "btd"), new_cache

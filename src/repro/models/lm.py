@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops
 from repro.models import params as P
 from repro.models import layers as L
 from repro.models import moe as M
@@ -415,14 +416,7 @@ class LM:
         for i, typ in enumerate(sch.pattern):
             sub = _init_sub_cache(cfg, typ, batch, max_len, kv_dtype)
             main[f"b{i}_{typ}"] = sub
-        if getattr(self, "decode_unroll", False):
-            # per-layer leaves: every layer's cache is its own buffer, so
-            # unrolled decode aliases updates in place (no scan xs/ys
-            # slice-copies) — §Perf hillclimb C
-            cache = {"main": [jax.tree_util.tree_map(lambda x: x + 0, main)
-                              for _ in range(sch.n_super)]}
-        else:
-            cache = {"main": _stack_cache(main, sch.n_super)}
+        cache = {"main": _stack_cache(main, sch.n_super)}
         if sch.tail:
             tail = _init_sub_cache(cfg, sch.tail[0], batch, max_len, kv_dtype)
             cache["tail"] = _stack_cache(tail, len(sch.tail))
@@ -518,18 +512,13 @@ class LM:
         sub-block name: (n_layers, num_slots, 3) int32 stacked over the
         scanned layers — or None when the cache was built without
         ``kernel_counters=True``."""
-        main = cache["main"]
-        if isinstance(main, list):
-            return None
-        out = {name: sub["kcnt"] for name, sub in main.items()
+        out = {name: sub["kcnt"] for name, sub in cache["main"].items()
                if "kcnt" in sub}
         return out or None
 
     @staticmethod
     def cache_is_paged(cache) -> bool:
-        main = cache["main"]
-        layer0 = main[0] if isinstance(main, list) else main
-        return any("pt" in sub for sub in layer0.values())
+        return any("pt" in sub for sub in cache["main"].values())
 
     def with_page_table(self, cache, pt) -> Any:
         """Return `cache` with every paged KV sub-block's page table
@@ -543,25 +532,16 @@ class LM:
                     for name, sub in tree.items()}
 
         new = dict(cache)
-        if isinstance(cache["main"], list):      # decode_unroll layout
-            new["main"] = [
-                {name: ({**sub, "pt": pt} if "pt" in sub else sub)
-                 for name, sub in layer.items()}
-                for layer in cache["main"]]
-        else:
-            new["main"] = set_in(cache["main"], self.sched.n_super)
+        new["main"] = set_in(cache["main"], self.sched.n_super)
         return new
 
     # ------------------------- cache index --------------------------
     def cache_index(self, cache) -> jax.Array:
         """Current write index of the decode cache: scalar, or (B,) when
         the cache has per-slot positions (serving engine)."""
-        main = cache["main"]
-        layer0 = main[0] if isinstance(main, list) else main
-        for sub in layer0.values():
+        for sub in cache["main"].values():
             if "idx" in sub:
-                idx = sub["idx"]
-                return idx if isinstance(main, list) else idx[0]
+                return sub["idx"][0]
         raise ValueError("cache has no indexed KV sub-block")
 
     def with_cache_index(self, cache, idx) -> Any:
@@ -579,13 +559,7 @@ class LM:
             return out
 
         new = dict(cache)
-        if isinstance(cache["main"], list):      # decode_unroll layout
-            new["main"] = [
-                {name: ({**sub, "idx": idx} if "idx" in sub else sub)
-                 for name, sub in layer.items()}
-                for layer in cache["main"]]
-        else:
-            new["main"] = set_in(cache["main"], self.sched.n_super)
+        new["main"] = set_in(cache["main"], self.sched.n_super)
         if "tail" in cache:
             new["tail"] = set_in(cache["tail"], len(self.sched.tail))
         return new
@@ -644,10 +618,6 @@ class LM:
         land at logical positions start[b]+s through the page table
         (length 0 = idle slot, nothing stored). Drops the win_* leaves.
         """
-        from repro.kernels import ops
-        assert not isinstance(cache["main"], list), \
-            "commit_verify expects the scanned (stacked) cache layout"
-
         def one(sub):
             if "win_k" not in sub:
                 return sub
@@ -745,17 +715,8 @@ class LM:
                 new_c[name] = nc
             return x, new_c
 
-        if getattr(self, "decode_unroll", False):
-            # unrolled layers over per-layer cache leaves: no scan xs/ys
-            # slice-copies; XLA aliases each layer's cache in place
-            new_main = []
-            for li in range(sch.n_super):
-                p_l = jax.tree_util.tree_map(lambda a: a[li], params["main"])
-                x, nc = superblock(x, (p_l, cache["main"][li]))
-                new_main.append(nc)
-        else:
-            x, new_main = self._scan_layers(superblock, x, params["main"],
-                                            cache["main"])
+        x, new_main = self._scan_layers(superblock, x, params["main"],
+                                        cache["main"])
         new_cache = {"main": new_main}
         if sch.tail:
             def tailblk(x, inp):
@@ -815,21 +776,9 @@ class LM:
         q = (x @ p_attn["wq"]["w"].astype(x.dtype)).reshape(B, S, H, D)
         if cfg.qk_norm:
             q = L.apply_rmsnorm(p_attn["q_norm"], q, cfg.norm_eps)
-        from repro.serve.flash_decode import (cross_attention_sharded,
-                                              decode_shard_plan)
-        from repro.sharding.ctx import current_sharder
-        sharder = current_sharder()
-        plan = decode_shard_plan(sharder, B, c["xk"].shape[1])
-        if plan is not None and "xvalid" not in c:
-            b_ax, s_ax = plan
-            out = cross_attention_sharded(
-                q, c["xk"], c["xv"], mesh=sharder.mesh,
-                batch_axes=b_ax, seq_axes=s_ax)
-        else:
-            # length-masked cross-attn (bucketed encoder prefill): keys
-            # past each row's true source length never contribute
-            out = L.attention(q, c["xk"].astype(x.dtype),
-                              c["xv"].astype(x.dtype), causal=False,
-                              kv_valid=c.get("xvalid"))
+        # with "xvalid" (bucketed encoder prefill), keys past each row's
+        # true source length never contribute
+        out = ops.cross_attention(q, c["xk"], c["xv"],
+                                  kv_valid=c.get("xvalid"))
         out = out.reshape(B, S, H * D)
         return out @ p_attn["wo"]["w"].astype(x.dtype)

@@ -1,15 +1,16 @@
 """Serve-step factories: one-token batched decode, batched prefill and
-the continuous-batching engine's tick/prefill, all sharded-cache aware.
+the continuous-batching engine's tick/prefill/verify, all sharded-cache
+aware.
 
-With ``tp_serve`` the cache is sequence-chunk sharded over "model": each
-shard computes attention over its chunk and XLA decomposes the softmax
-reduction into the flash-decoding partial-max/denominator combine. Works
-for any head count and any cache length (incl. 500k).
-
-Every factory wraps the model call in ``sharding_ctx``, so
-``serve.engine.ServeEngine`` composes with distribution strategies
-instead of duplicating an unsharded decode step: the engine jits these
-factories directly (dense and paged KV layouts alike).
+Every factory wraps the model call in ``sharding_ctx``. With
+``tp_serve`` the cache is sequence-chunk (or page-chunk) sharded over
+"model", and ``kernels/ops.py`` then runs the flash-decoding entries of
+``kernels/sharded.py``: each shard computes attention over its chunk and
+the partials combine with one pmax+psum. Works for any head count and
+any cache length (incl. 500k). So ``serve.engine.ServeEngine`` composes
+with distribution strategies instead of duplicating an unsharded decode
+step: the engine jits these factories directly (dense and paged KV
+layouts alike).
 """
 from __future__ import annotations
 

@@ -37,8 +37,10 @@ Production-shaped serving over a fixed-size decode batch:
     ``spec_rollback=True`` on the paged layout the commit stops at the
     accept point (`LM.commit_verify`) and they never reach the pool.
 
-The jitted tick/prefill come from `serve.decode`'s step factories
-(sharding-context aware, so the engine composes with `tp_serve`). The
+The jitted tick/prefill/verify come from `serve.decode`'s step
+factories, which run the model in the strategy's sharding context: under
+`tp_serve`, `kernels/ops.py` picks the sharded attention of
+`kernels/sharded.py`, so the engine composes with it unchanged. The
 engine needs every sub-block to carry an indexed KV cache, so it
 supports the "dense" and "moe" families; other families are served by
 the legacy token-loop in `launch/serve.py`.

@@ -23,8 +23,8 @@ The paged heap eliminates both:
 Host-side bookkeeping lives here (allocator, page tables, prefix
 index); the device-side pool layout and gather/scatter live in
 `models/lm.py` (`init_paged_cache`) + `kernels/ref.py`
-(`paged_update`/`paged_gather`) + `serve/flash_decode.py` (sharded
-paged decode). `ServeEngine(kv_layout="paged")` drives it.
+(`paged_update`/`paged_gather`) + `kernels/sharded.py` (sharded
+paged decode, which `kernels/ops.py` picks under a sharded pool). `ServeEngine(kv_layout="paged")` drives it.
 """
 from __future__ import annotations
 

@@ -11,7 +11,7 @@ Three strategies (DESIGN.md §4):
   compute; GSPMD all-gathers block weights inside the scan (overlappable).
 * ``tp_serve`` — decoding. Megatron TP weights; KV cache sharded over
   "model" by sequence chunks — each shard computes partial attention and
-  XLA decomposes the softmax reduction across shards (flash-decoding).
+  the partials combine flash-decoding style (``kernels/sharded.py``).
   For models whose TP-16 bf16 weights exceed one chip's HBM, weights are
   spread over ("data","model") instead (weight-gathered serving).
 
@@ -236,7 +236,7 @@ class TpServe(Strategy):
     def paged_cache_specs(self, cache_abstract, batch: int) -> Any:
         """Paged-pool analogue of cache_specs: the page dimension of each
         (L, P, page, Hkv, D) pool chunks over 'model' (pages play the
-        dense layout's sequence-shard role — serve/flash_decode.py's
+        dense layout's sequence-shard role — kernels/sharded.py's
         paged combine), page tables/indices shard over dp with the slot
         batch when divisible."""
         mesh = self.mesh

@@ -20,9 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as PS
 
-def shard_map(f, mesh, in_specs, out_specs):
-    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+from repro.sharding.ctx import shard_map
 
 
 def _plain(x, w, labels, z_loss):

@@ -192,23 +192,6 @@ def test_window_kernel_store_equals_defer_attention():
     np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
 
 
-def test_verify_wrapper_modes_match_window_kernel():
-    from repro.kernels.paged_verify import paged_verify_attention
-    pk, pv = _pools(jnp.float32)
-    q, kw, vw = _rows(3, seed=17)
-    pt, idx = jnp.asarray(HOSTILE_PT), jnp.asarray(HOSTILE_IDX_W)
-    for mode, store in (("overwrite", True), ("defer", False)):
-        got = paged_verify_attention(q, kw, vw, pk, pv, pt, idx,
-                                     mode=mode, interpret=True)
-        want = paged_window_attention(q, kw, vw, pk, pv, pt, idx,
-                                      store=store, interpret=True)
-        for g, w in zip(got, want):
-            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
-    with pytest.raises(AssertionError):
-        paged_verify_attention(q, kw, vw, pk, pv, pt, idx, mode="bogus",
-                               interpret=True)
-
-
 def test_ops_dispatch_parity(monkeypatch):
     """ops.paged_decode / ops.paged_window agree between the two
     dispatch targets (counters included) on the hostile table."""
@@ -227,6 +210,56 @@ def test_ops_dispatch_parity(monkeypatch):
     np.testing.assert_array_equal(np.asarray(ck_p), np.asarray(ck_r))
     np.testing.assert_array_equal(np.asarray(cv_p), np.asarray(cv_r))
     np.testing.assert_array_equal(np.asarray(c_p), np.asarray(c_r))
+
+
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+@pytest.mark.parametrize("plan", [False, True], ids=["no_plan", "plan"])
+@pytest.mark.parametrize("call", ["decode", "verify", "prefill", "defer"])
+def test_ops_dispatch_routes_sharded_with_plan(monkeypatch, call, plan,
+                                               backend):
+    """``ops.paged_decode`` and the stored verify window run their
+    sharded entry exactly when the active sharder has a plan for the
+    pool (forced here, over a one-device "model" mesh); a prefill window
+    and a deferred verify never do. On a stacked pool the sharded call
+    cuts the layer out, writes it back and counts the stores outside the
+    shard_map, so outputs, pools and counters match the unsharded call."""
+    from jax.sharding import Mesh
+    from repro.kernels import sharded
+    from repro.sharding.ctx import Sharder, sharding_ctx
+    monkeypatch.setattr(kops, "_use_pallas", lambda: backend == "pallas")
+    sk, sv = _stacked_pools()
+    S = 1 if call == "decode" else 3
+    q, kn, vn = _rows(S, seed=41)
+    pt = jnp.asarray(HOSTILE_PT)
+    idx = jnp.asarray(HOSTILE_IDX if S == 1 else HOSTILE_IDX_W)
+    fn = (kops.paged_decode if call == "decode" else
+          partial(kops.paged_window, store=call != "defer",
+                  verify=call != "prefill"))
+    run = jax.jit(lambda *a: fn(*a, layer=1, counters=True))
+    args = (q, kn, vn, sk, sv, pt, idx)
+    want = run(*args)
+
+    routed = []
+    for name in ("decode_paged_attention_sharded",
+                 "verify_paged_attention_sharded"):
+        def spy(*a, _real=getattr(sharded, name), _name=name, **kw):
+            routed.append(_name)
+            return _real(*a, **kw)
+        monkeypatch.setattr(sharded, name, spy)
+    monkeypatch.setattr(sharded, "paged_shard_plan",
+                        lambda *a: ((), ("model",)) if plan else None)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("model",))
+    with sharding_ctx(Sharder(mesh, {})):
+        got = jax.jit(lambda *a: fn(*a, layer=1, counters=True))(*args)
+    entry = {"decode": "decode_paged_attention_sharded",
+             "verify": "verify_paged_attention_sharded"}.get(call)
+    assert routed == ([entry] if plan and entry else [])
+    live = np.asarray(idx) >= 0
+    np.testing.assert_allclose(np.asarray(got[0])[live],
+                               np.asarray(want[0])[live],
+                               atol=2e-5, rtol=2e-5)
+    for g, w in zip(got[1:], want[1:]):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 # ---------------------------------------------------------------------
@@ -505,7 +538,7 @@ import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 from repro.kernels import ops
-from repro.serve import flash_decode as fd
+from repro.kernels import sharded as sh
 
 mesh = Mesh(np.array(jax.devices()).reshape(2), ("model",))
 B, Hq, Hkv, D = 3, 4, 2, 8
@@ -523,8 +556,8 @@ for dtype in (jnp.float32, jnp.bfloat16):
     qw = jax.random.normal(ks[5], (B, 3, Hq, D), jnp.float32)
     kw = jax.random.normal(ks[6], (B, 3, Hkv, D), jnp.float32)
     vw = jax.random.normal(ks[7], (B, 3, Hkv, D), jnp.float32)
-    for entry, a in ((fd.decode_paged_attention_sharded, (q, kn, vn)),
-                     (fd.verify_paged_attention_sharded, (qw, kw, vw))):
+    for entry, a in ((sh.decode_paged_attention_sharded, (q, kn, vn)),
+                     (sh.verify_paged_attention_sharded, (qw, kw, vw))):
         with mesh:
             ops._use_pallas = lambda: False
             o_r, ck_r, cv_r = entry(*a, pool_k, pool_v, pt, idx, mesh=mesh,

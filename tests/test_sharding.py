@@ -95,7 +95,7 @@ with mesh:
     assert float(jnp.abs(gw - rw).max()) < 1e-5
 
 # --- flash decoding == masked reference -------------------------------
-from repro.serve.flash_decode import decode_attention_sharded
+from repro.kernels.sharded import decode_attention_sharded
 from repro.kernels.ref import attention_ref
 B, Smax, Hq, Hkv, D = 2, 64, 4, 2, 16
 q = jax.random.normal(key, (B, 1, Hq, D))
@@ -115,7 +115,7 @@ assert float(jnp.abs(out - want).max()) < 1e-4, float(jnp.abs(out - want).max())
 assert float(jnp.abs(nck - ck_ref).max()) == 0.0
 
 # --- paged flash decoding == page-table-gathered reference -------------
-from repro.serve.flash_decode import decode_paged_attention_sharded
+from repro.kernels.sharded import decode_paged_attention_sharded
 from repro.kernels.ref import paged_gather, paged_update
 P, ps, M = 16, 8, 4                     # pool pages shard 4-way over model
 pk = jax.random.normal(jax.random.PRNGKey(7), (P, ps, Hkv, D))
@@ -138,7 +138,7 @@ assert float(jnp.abs(npk - rpk).max()) == 0.0   # idle-slot store dropped
 assert float(jnp.abs(npv - rpv).max()) == 0.0
 
 # --- width-k speculative verify == page-table-gathered reference -------
-from repro.serve.flash_decode import verify_paged_attention_sharded
+from repro.kernels.sharded import verify_paged_attention_sharded
 W = 3
 qw = jax.random.normal(jax.random.PRNGKey(9), (B, W, Hq, D))
 knw = jax.random.normal(jax.random.PRNGKey(10), (B, W, Hkv, D))
